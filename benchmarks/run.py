@@ -15,6 +15,8 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro import runtime
+
 from . import (fig1_convergence, fig23_scaling, fig4_transfer, fleet_bench,
                gpu_bench, path_sweep, proj_bench, roofline, serve_bench,
                stream_bench, table1_compare, xupdate_bench)
@@ -27,6 +29,7 @@ def main() -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="CI-sized subset (projection + x-update engines)")
     args = ap.parse_args()
+    runtime.enable_compile_cache()
 
     t0 = time.time()
     if args.smoke:

@@ -4,11 +4,13 @@ shard_map engine (falling back to the single-process reference engine when
 the mesh has no real parallelism or the data doesn't tile it — see
 ``repro.api.select_engine``).
 
-Run with emulated devices (the launcher does this for you on CPU):
+The mesh is built from the devices present: (nodes=4, feat=1) on a
+four-chip TPU host, (4, 2) on the CPU, where the script asks XLA for 8
+emulated host devices unless ``XLA_FLAGS`` already sets a count:
 
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-        PYTHONPATH=src python examples/distributed_sparse_fit.py
+    PYTHONPATH=src python examples/distributed_sparse_fit.py
 """
+import math
 import os
 
 if "--xla_force_host_platform_device_count" not in os.environ.get(
@@ -24,12 +26,16 @@ from repro.data.synthetic import SyntheticSpec, make_sparse_regression
 
 
 def main():
-    mesh = jax.make_mesh((4, 2), ("nodes", "feat"))
-    print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
-          f"({mesh.devices.size} devices)")
-
     spec = SyntheticSpec(n_nodes=4, m_per_node=400, n_features=256,
                          sparsity_level=0.8)
+    # the paper's N nodes over as many devices as tile them; the rest of
+    # the devices split the features
+    n_dev = len(jax.devices())
+    nodes = math.gcd(n_dev, spec.n_nodes)
+    mesh = jax.make_mesh((nodes, n_dev // nodes), ("nodes", "feat"))
+    print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))} "
+          f"({mesh.devices.size} {jax.devices()[0].platform} devices)")
+
     As, bs, x_true = make_sparse_regression(0, spec)
 
     # engine="auto": the mesh is available and the (N, m, n) data tiles it,

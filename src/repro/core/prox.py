@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 
 from .losses import Loss
+from ..runtime import F32_DOT
 from ..kernels.ops import (gram_auto, matvec_auto, normal_matvec_auto,
                            rmatvec_auto)
 
@@ -122,7 +123,8 @@ def ridge_prox_eigh(f: EighRidgeFactors, q: Array, rho_c: Array | float,
     """Same prox as :func:`ridge_prox_factorized` but with a dynamic shift
     c = sigma + rho_c: x = V diag(1/(evals + c)) V^T (A^T b + rho_c q)."""
     rhs = f.Atb + rho_c * q
-    return f.V @ ((f.V.T @ rhs) / (f.evals + sigma + rho_c))
+    y = jnp.dot(f.V.T, rhs, precision=F32_DOT) / (f.evals + sigma + rho_c)
+    return jnp.dot(f.V, y, precision=F32_DOT)
 
 
 # ------------------------------------------------------------ woodbury ----
@@ -179,7 +181,8 @@ def woodbury_prox(f: WoodburyFactors, q: Array, rho_c: Array | float) -> Array:
 def _woodbury_eigh_solve(f: WoodburyEighFactors, rhs: Array,
                          c: Array | float) -> Array:
     t = matvec_auto(f.A, rhs)
-    y = f.U @ ((f.U.T @ t) / (f.evals + c))
+    y = jnp.dot(f.U.T, t, precision=F32_DOT) / (f.evals + c)
+    y = jnp.dot(f.U, y, precision=F32_DOT)
     return (rhs - rmatvec_auto(f.A, y)) / c
 
 
@@ -213,8 +216,9 @@ def col_sumsq(A: Array) -> Array:
     (and emits) in f32; the f32 path is untouched."""
     acc = _accum(A.dtype)
     if acc == A.dtype:
-        return jnp.einsum("mn,mn->n", A, A)
-    return jnp.einsum("mn,mn->n", A, A, preferred_element_type=acc)
+        return jnp.einsum("mn,mn->n", A, A, precision=F32_DOT)
+    return jnp.einsum("mn,mn->n", A, A, preferred_element_type=acc,
+                      precision=F32_DOT)
 
 
 @jax.tree_util.register_dataclass
@@ -246,7 +250,8 @@ def pcg(matvec: Callable[[Array], Array], rhs: Array, x0: Array,
     feature shards (the matvec carries its own psum of the partial
     predictions) and on a single device the two engines are bit-identical.
     """
-    dot = dot_fn if dot_fn is not None else (lambda u, w: jnp.vdot(u, w))
+    dot = dot_fn if dot_fn is not None else (
+        lambda u, w: jnp.vdot(u, w, precision=F32_DOT))
     r0 = rhs - matvec(x0)
     z0 = precond(r0)
     rz0 = dot(r0, z0)
@@ -469,10 +474,10 @@ def _cg(matvec: Callable[[Array], Array], rhs: Array, iters: int,
     def body(state):
         x, r, p, rs, k = state
         Ap = matvec(p)
-        alpha = rs / jnp.maximum(jnp.vdot(p, Ap), 1e-30)
+        alpha = rs / jnp.maximum(jnp.vdot(p, Ap, precision=F32_DOT), 1e-30)
         x = x + alpha * p
         r = r - alpha * Ap
-        rs_new = jnp.vdot(r, r)
+        rs_new = jnp.vdot(r, r, precision=F32_DOT)
         p = r + (rs_new / jnp.maximum(rs, 1e-30)) * p
         return x, r, p, rs_new, k + 1
 
@@ -480,7 +485,8 @@ def _cg(matvec: Callable[[Array], Array], rhs: Array, iters: int,
         _, _, _, rs, k = state
         return (rs > tol) & (k < iters)
 
-    x, *_ = jax.lax.while_loop(cond, body, (x0, rhs, rhs, jnp.vdot(rhs, rhs),
+    rs0 = jnp.vdot(rhs, rhs, precision=F32_DOT)
+    x, *_ = jax.lax.while_loop(cond, body, (x0, rhs, rhs, rs0,
                                             jnp.asarray(0)))
     return x
 

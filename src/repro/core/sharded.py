@@ -97,12 +97,12 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from . import bilinear, prox
 from .bicadmm import BiCADMMConfig, _zt_update
 from .. import faults, runtime
+from ..runtime import F32_DOT
 from .losses import Loss, get_loss
 from .results import (FitResult, SparsePath, classify_status,
                       divergence_probe)
@@ -112,6 +112,17 @@ from ..kernels.ops import (block_matvec, block_rmatvec, gram_auto,
 Array = jax.Array
 
 X_UPDATE_MODES = ("auto", "subsolver", "cg")
+
+
+def auto_axes_mesh(mesh: Mesh) -> Mesh:
+    """``mesh`` with every axis in Auto mode. ``jax.make_mesh`` builds
+    Explicit axes, which put shardings into array types; the engine's
+    shard_map programs and its eager post-processing of their outputs are
+    written for Auto axes, so it runs on this view of the caller's mesh
+    (same devices, same names and shape)."""
+    if mesh.are_all_axes_auto:
+        return mesh
+    return mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
 
 
 class ShardedState(NamedTuple):
@@ -271,6 +282,7 @@ class ShardedBiCADMM:
     def __post_init__(self):
         if isinstance(self.loss, str):
             self.loss = get_loss(self.loss, self.n_classes)
+        self.mesh = auto_axes_mesh(self.mesh)
         if self.projection not in ("ladder_exact", "exact", "batched",
                                    "bisect"):
             raise ValueError(f"unknown projection mode {self.projection!r}")
@@ -369,11 +381,12 @@ class ShardedBiCADMM:
                 acc = prox._accum(A_blk.dtype)
                 if acc == A_blk.dtype:
                     colsq = jnp.einsum("jmn,jmn->jn", A_blk[None],
-                                       A_blk[None])[0]
+                                       A_blk[None], precision=F32_DOT)[0]
                 else:
                     colsq = jnp.einsum("jmn,jmn->jn", A_blk[None],
                                        A_blk[None],
-                                       preferred_element_type=acc)[0]
+                                       preferred_element_type=acc,
+                                       precision=F32_DOT)[0]
                 return colsq[None, None]
             out_specs = P(nodes, feat, None)
         else:
@@ -386,9 +399,9 @@ class ShardedBiCADMM:
 
         key = ("setup", n, mode)
         if key not in self._jit_cache:
-            self._jit_cache[key] = jax.jit(shard_map(
+            self._jit_cache[key] = jax.jit(jax.shard_map(
                 setup_run, mesh=self.mesh, in_specs=(P(nodes, feat),),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
         return self._jit_cache[key](A_p)
 
     def _prepare(self, A_global: Array, n: int) -> tuple[Array, Array]:
@@ -484,8 +497,8 @@ class ShardedBiCADMM:
             else:
                 x_solve = None
         else:
-            mm_fwd = lambda x: A_blk @ x
-            mm_t = lambda ct: A_blk.T @ ct
+            mm_fwd = lambda x: jnp.dot(A_blk, x, precision=F32_DOT)
+            mm_t = lambda ct: jnp.dot(A_blk.T, ct, precision=F32_DOT)
             x_solve = chol_solve if chol is not None else None
 
         def flat(x):  # (nb, K) -> (nbK,) for the projection helpers
@@ -558,13 +571,16 @@ class ShardedBiCADMM:
             A1 = A_blk[None]
 
             def cg_fwd(p):                                 # (nb,) -> (m_loc,)
-                return jnp.einsum("jmn,jn->jm", A1, p[None])[0]
+                return jnp.einsum("jmn,jn->jm", A1, p[None],
+                                  precision=F32_DOT)[0]
 
             def cg_adj(w):                                 # (m_loc,) -> (nb,)
-                return jnp.einsum("jmn,jm->jn", A1, w[None])[0]
+                return jnp.einsum("jmn,jm->jn", A1, w[None],
+                                  precision=F32_DOT)[0]
 
             def cg_dot(u2, w2):
-                return psum_f(jnp.einsum("jn,jn->j", u2[None], w2[None])[0])
+                return psum_f(jnp.einsum("jn,jn->j", u2[None], w2[None],
+                                         precision=F32_DOT)[0])
 
             Atb = cg_adj(b_blk)                            # (nb,)
             inv = 1.0 / (xfac + c)                         # Jacobi precond
@@ -598,12 +614,12 @@ class ShardedBiCADMM:
 
         def zt_update_sharded(z0, t0, wc, s, v):
             a = N * cfg.rho_c
-            ss = psum_f(jnp.vdot(s, s))
+            ss = psum_f(jnp.vdot(s, s, precision=F32_DOT))
             L = a + rho_b * (ss + 1.0)
             step = 1.0 / L
 
             def grads(z, t):
-                r = psum_f(jnp.vdot(s, z)) - t + v
+                r = psum_f(jnp.vdot(s, z, precision=F32_DOT)) - t + v
                 return a * (z - wc) + rho_b * r * s, -rho_b * r
 
             def body(_, carry):
@@ -709,7 +725,7 @@ class ShardedBiCADMM:
             theta = jnp.where(u_max > 0, c_cl / jnp.where(u_max > 0, u_max, 1.0), 0.0)
             s_new = unflat(theta * s_star)
             u_new = st.u + x_eff - z_new
-            gval = psum_f(jnp.vdot(z_new, s_new)) - t_new   # g = z.s - t
+            gval = psum_f(jnp.vdot(z_new, s_new, precision=F32_DOT)) - t_new
             v_new = st.v + gval
             # residuals (14): p_r = sum_i ||x_i - z||; local: ssq over feat
             loc_sq = jnp.sum((x_new - z_new) ** 2)
@@ -811,9 +827,9 @@ class ShardedBiCADMM:
             # the state pytree is donated: its iterate buffers are reused
             # in place by the while-loop (fit consumes a passed-in state —
             # keep using the returned result.state)
-            self._jit_cache[key] = jax.jit(shard_map(
+            self._jit_cache[key] = jax.jit(jax.shard_map(
                 run, mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False), donate_argnums=(3,))
+                out_specs=out_specs, check_vma=False), donate_argnums=(3,))
         (z, k, p_r, d_r, b_r, t), hist, gs = \
             self._jit_cache[key](A_p, b_global, xfac, state)
 
@@ -884,9 +900,9 @@ class ShardedBiCADMM:
         key = ("path", n, b_global.ndim, warm_start)
         if key not in self._jit_cache:
             # state donated: path iterate buffers are reused in place
-            self._jit_cache[key] = jax.jit(shard_map(
+            self._jit_cache[key] = jax.jit(jax.shard_map(
                 run, mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False), donate_argnums=(4,))
+                out_specs=out_specs, check_vma=False), donate_argnums=(4,))
         (z, k, p_r, d_r, b_r), gs = \
             self._jit_cache[key](A_p, b_global, xfac, kaps, state)
 

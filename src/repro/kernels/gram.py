@@ -26,18 +26,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .. import runtime
+from .matvec import _accumulate, _f32_dot, _masked_tail
 
 Array = jax.Array
 
 _GPU_MIN = 16
 
 
-def _gram_kernel(x_ref, y_ref, o_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jnp.dot(x_ref[...].T, y_ref[...],
-                          preferred_element_type=jnp.float32)
+def _gram_kernel(x_ref, y_ref, o_ref, *, last: int, tail: int):
+    def dot(valid):
+        x, y = x_ref[...], y_ref[...]
+        if valid is not None:
+            x, y = _masked_tail(x, 0, valid), _masked_tail(y, 0, valid)
+        return _f32_dot(x.T, y)
+    _accumulate(o_ref, pl.program_id(2), last, tail, dot)
 
 
 def gram(a: Array, *, block_m: int = 512, block_n: int = 128,
@@ -51,26 +53,25 @@ def gram(a: Array, *, block_m: int = 512, block_n: int = 128,
                                              "interpret"))
 def _gram_xy(x: Array, y: Array, *, block_m: int, block_n: int,
              interpret: bool) -> Array:
+    # x and y are read in place: cdiv grid, clipped edge tiles of G, and
+    # the ragged tail of the sample (contraction) axis masked in-kernel.
     m, nx = x.shape
     my, ny = y.shape
     assert m == my
-    bm = min(block_m, _rup(m, 8))
-    bnx = min(block_n, _rup(nx, 128))
-    bny = min(block_n, _rup(ny, 128))
-    xp = _pad2(x, bm, bnx)
-    yp = _pad2(y, bm, bny)
-    ni, nj, nk = xp.shape[1] // bnx, yp.shape[1] // bny, xp.shape[0] // bm
+    bm = m if m <= block_m else block_m
+    bnx = nx if nx <= block_n else block_n
+    bny = ny if ny <= block_n else block_n
+    nk = pl.cdiv(m, bm)
     out = pl.pallas_call(
-        _gram_kernel,
-        grid=(ni, nj, nk),
+        functools.partial(_gram_kernel, last=nk - 1, tail=m % bm),
+        grid=(pl.cdiv(nx, bnx), pl.cdiv(ny, bny), nk),
         in_specs=[pl.BlockSpec((bm, bnx), lambda i, j, k: (k, i)),
                   pl.BlockSpec((bm, bny), lambda i, j, k: (k, j))],
         out_specs=pl.BlockSpec((bnx, bny), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((xp.shape[1], yp.shape[1]),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nx, ny), jnp.float32),
         interpret=interpret,
-    )(xp, yp)
-    return out[:nx, :ny]
+    )(x, y)
+    return out
 
 
 def gram_xy(x: Array, y: Array, *, block_m: int = 512, block_n: int = 128,
@@ -88,8 +89,8 @@ def _gram_kernel_gpu(x_ref, y_ref, o_ref, *, nsteps: int, bm: int):
     # program, sample blocks contracted inside (parallel Triton programs
     # cannot revisit a shared accumulator tile).
     def body(k, acc):
-        x_blk = pl.load(x_ref, (pl.dslice(k * bm, bm), slice(None)))
-        y_blk = pl.load(y_ref, (pl.dslice(k * bm, bm), slice(None)))
+        x_blk = x_ref[pl.ds(k * bm, bm), :]
+        y_blk = y_ref[pl.ds(k * bm, bm), :]
         return acc + jnp.dot(x_blk.T, y_blk,
                              preferred_element_type=jnp.float32)
 

@@ -15,6 +15,11 @@ plus an axpy. Two Pallas implementations live here:
   tiled into MXU-aligned VMEM blocks with the reduction axis innermost in
   the grid, so each f32 output tile stays resident across the whole sweep
   of the contracted dimension (grid iterations are sequential on TPU).
+  A is read in place — never padded or copied, so a solver loop around
+  these kernels holds no second A in HBM: the grid covers A with cdiv
+  blocks, edge tiles of the output are clipped on write, the ragged edge
+  of the contraction is masked in-kernel, and only the small operand is
+  zero-padded to whole blocks.
 * **GPU (Triton)** — ``matvec_gpu`` / ``rmatvec_gpu`` / ``normal_matvec_gpu``:
   Triton grid programs run in *parallel* with no cross-program memory
   ordering, so the TPU accumulation pattern would race. The GPU kernels
@@ -75,29 +80,69 @@ def _pad2(a: Array, bm: int, bn: int) -> Array:
     return jnp.pad(a, ((0, _rup(m, bm) - m), (0, _rup(n, bn) - n)))
 
 
-def _clamp_blocks(block_m: int, block_n: int, m: int, n: int,
-                  kp: int) -> tuple[int, int]:
-    """Shrink the A-tile rows until A-tile + operand + accumulator tiles fit
-    the VMEM budget. The lane (last) dims stay 128-multiples."""
-    bm = min(block_m, _rup(m, 8))
-    bn = min(block_n, _rup(n, _LANE))
-    while bm > 8 and bm * bn + (bm + bn) * kp > _VMEM_ELEMS:
-        bm = max(8, bm // 2)
-    return bm, bn
-
-
 def _as_2d(x: Array) -> tuple[Array, bool]:
     return (x[:, None], True) if x.ndim == 1 else (x, False)
 
 
-# ------------------------------------------------------------ TPU (Mosaic) --
+def _tpu_blocks(block_m: int, block_n: int, m: int, n: int,
+                kp: int) -> tuple[int, int]:
+    """Mosaic tile of A: rows a multiple of 8, lanes a multiple of 128,
+    or the full extent of a dimension shorter than the requested block
+    (both are legal block shapes). The row tile halves until A-tile +
+    operand + accumulator tiles fit the VMEM budget."""
+    bn = n if n <= block_n else block_n
+    bm = block_m
+    while bm > 8 and bm * bn + (bm + bn) * kp > _VMEM_ELEMS:
+        bm = max(8, bm // 2)
+    return (m if m <= bm else bm), bn
 
-def _mv_kernel(a_ref, x_ref, o_ref):
-    @pl.when(pl.program_id(1) == 0)
+
+def _masked_tail(a: Array, axis: int, valid: int) -> Array:
+    """Zero the entries of an A tile past ``valid`` along ``axis``. The
+    last block of a ragged contraction reads past the edge of A, and what
+    it reads there is unspecified (possibly NaN), so it is masked before
+    it meets the zero-padded operand."""
+    idx = jax.lax.broadcasted_iota(jnp.int32, a.shape, axis)
+    return jnp.where(idx < valid, a, jnp.zeros_like(a))
+
+
+def _f32_dot(a: Array, b: Array) -> Array:
+    """a @ b accumulated in f32. f32 operands keep f32 products: the
+    MXU's default precision would round them to bf16 first."""
+    prec = runtime.F32_DOT if jnp.result_type(a, b) == jnp.float32 else None
+    return jnp.dot(a, b, preferred_element_type=jnp.float32, precision=prec)
+
+
+def _accumulate(o_ref, k, last: int, tail: int, dot) -> None:
+    """``o_ref += dot(mask)`` for contraction step ``k``: the first step
+    zeroes the resident accumulator, and only the last step of a ragged
+    contraction (``tail`` > 0 valid entries) pays for the mask."""
+    @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jnp.dot(a_ref[...], x_ref[...],
-                          preferred_element_type=jnp.float32)
+
+    if tail == 0:
+        o_ref[...] += dot(None)
+        return
+
+    @pl.when(k != last)
+    def _body():
+        o_ref[...] += dot(None)
+
+    @pl.when(k == last)
+    def _tail():
+        o_ref[...] += dot(tail)
+
+
+# ------------------------------------------------------------ TPU (Mosaic) --
+
+def _mv_kernel(a_ref, x_ref, o_ref, *, last: int, tail: int):
+    def dot(valid):
+        a = a_ref[...]
+        if valid is not None:
+            a = _masked_tail(a, 1, valid)
+        return _f32_dot(a, x_ref[...])
+    _accumulate(o_ref, pl.program_id(1), last, tail, dot)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
@@ -108,20 +153,19 @@ def _matvec(a: Array, x: Array, *, block_m: int, block_n: int,
     x2, was_1d = _as_2d(x)
     k = x2.shape[1]
     kp = _rup(k, _LANE)
-    bm, bn = _clamp_blocks(block_m, block_n, m, n, kp)
-    ap = _pad2(a, bm, bn)
+    bm, bn = _tpu_blocks(block_m, block_n, m, n, kp)
+    mi, nk = pl.cdiv(m, bm), pl.cdiv(n, bn)
     xp = _pad2(x2, bn, kp)
-    mi, nk = ap.shape[0] // bm, ap.shape[1] // bn
     out = pl.pallas_call(
-        _mv_kernel,
+        functools.partial(_mv_kernel, last=nk - 1, tail=n % bn),
         grid=(mi, nk),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
                   pl.BlockSpec((bn, kp), lambda i, j: (j, 0))],
         out_specs=pl.BlockSpec((bm, kp), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ap.shape[0], kp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((m, kp), jnp.float32),
         interpret=interpret,
-    )(ap, xp)
-    out = out[:m, :k]
+    )(a, xp)
+    out = out[:, :k]
     return out[:, 0] if was_1d else out
 
 
@@ -132,12 +176,13 @@ def matvec(a: Array, x: Array, *, block_m: int = 256, block_n: int = 512,
                    interpret=runtime.resolve_interpret(interpret))
 
 
-def _rmv_kernel(a_ref, y_ref, o_ref):
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
-    o_ref[...] += jnp.dot(a_ref[...].T, y_ref[...],
-                          preferred_element_type=jnp.float32)
+def _rmv_kernel(a_ref, y_ref, o_ref, *, last: int, tail: int):
+    def dot(valid):
+        a = a_ref[...]
+        if valid is not None:
+            a = _masked_tail(a, 0, valid)
+        return _f32_dot(a.T, y_ref[...])
+    _accumulate(o_ref, pl.program_id(1), last, tail, dot)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
@@ -148,20 +193,19 @@ def _rmatvec(a: Array, y: Array, *, block_m: int, block_n: int,
     y2, was_1d = _as_2d(y)
     k = y2.shape[1]
     kp = _rup(k, _LANE)
-    bm, bn = _clamp_blocks(block_m, block_n, m, n, kp)
-    ap = _pad2(a, bm, bn)
+    bm, bn = _tpu_blocks(block_m, block_n, m, n, kp)
+    ni, mk = pl.cdiv(n, bn), pl.cdiv(m, bm)
     yp = _pad2(y2, bm, kp)
-    ni, mk = ap.shape[1] // bn, ap.shape[0] // bm
     out = pl.pallas_call(
-        _rmv_kernel,
+        functools.partial(_rmv_kernel, last=mk - 1, tail=m % bm),
         grid=(ni, mk),
         in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
                   pl.BlockSpec((bm, kp), lambda i, j: (j, 0))],
         out_specs=pl.BlockSpec((bn, kp), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((ap.shape[1], kp), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n, kp), jnp.float32),
         interpret=interpret,
-    )(ap, yp)
-    out = out[:n, :k]
+    )(a, yp)
+    out = out[:, :k]
     return out[:, 0] if was_1d else out
 
 
@@ -194,8 +238,8 @@ def _mv_kernel_gpu(a_ref, x_ref, o_ref, *, nsteps: int, bn: int):
     # a_ref (bm, n_pad) window, x_ref (n_pad, kp): contract inside the
     # program — parallel Triton programs cannot share an accumulator tile.
     def body(j, acc):
-        a_blk = pl.load(a_ref, (slice(None), pl.dslice(j * bn, bn)))
-        x_blk = pl.load(x_ref, (pl.dslice(j * bn, bn), slice(None)))
+        a_blk = a_ref[:, pl.ds(j * bn, bn)]
+        x_blk = x_ref[pl.ds(j * bn, bn), :]
         return acc + jnp.dot(a_blk, x_blk, preferred_element_type=jnp.float32)
 
     acc = jnp.zeros(o_ref.shape, jnp.float32)
@@ -239,8 +283,8 @@ def _rmv_kernel_gpu(a_ref, y_ref, o_ref, *, nsteps: int, bm: int):
     # a_ref (m_pad, bn) window, y_ref (m_pad, kp): one n-tile per program,
     # fori_loop over the sample blocks of the adjoint contraction.
     def body(j, acc):
-        a_blk = pl.load(a_ref, (pl.dslice(j * bm, bm), slice(None)))
-        y_blk = pl.load(y_ref, (pl.dslice(j * bm, bm), slice(None)))
+        a_blk = a_ref[pl.ds(j * bm, bm), :]
+        y_blk = y_ref[pl.ds(j * bm, bm), :]
         return acc + jnp.dot(a_blk.T, y_blk,
                              preferred_element_type=jnp.float32)
 

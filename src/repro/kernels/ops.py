@@ -33,6 +33,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from .. import runtime
 from .bisect_proj import ladder_stats, ladder_stats_gpu
@@ -48,7 +49,8 @@ __all__ = ["gram", "gram_auto", "gram_gpu", "gram_xy", "gram_xy_gpu",
            "flash_attention", "flash_attention_flat", "matvec",
            "matvec_auto", "matvec_gpu", "rmatvec", "rmatvec_auto",
            "rmatvec_gpu", "normal_matvec", "normal_matvec_auto",
-           "normal_matvec_gpu", "block_matvec", "block_rmatvec"]
+           "normal_matvec_gpu", "block_matvec", "block_rmatvec",
+           "kernel_operand"]
 
 
 def _out(x: Array, like: Array, out_dtype) -> Array:
@@ -69,6 +71,24 @@ def _ladder_stats_jnp(az: Array, thetas: Array) -> Array:
         thetas.astype(jnp.float32)[None, :]
     return jnp.stack([jnp.sum(jnp.maximum(diff, 0.0), axis=0),
                       jnp.sum((diff > 0).astype(jnp.float32), axis=0)])
+
+
+def _row_major(a: Array) -> Array:
+    """``a`` in the row-major device layout the Mosaic kernels read.
+
+    A TPU may store an array in another dimension order (for an
+    (N, m, n) design with n not a multiple of 128, the one that pads its
+    (8, 128) tiles least puts m minor). A kernel operand must be
+    row-major, so every compiled fit would start by copying such an A
+    into a row-major temp; relaying it out once, where the engine first
+    sees the data, keeps that copy out of the fits."""
+    fmt = getattr(a, "format", None)
+    want = tuple(range(a.ndim))
+    if (fmt is None or fmt.layout is None
+            or tuple(fmt.layout.major_to_minor) == want):
+        return a
+    return jax.device_put(a, Format(Layout(major_to_minor=want),
+                                    fmt.sharding))
 
 
 def _flash_gpu(*_args, **_kw):
@@ -133,6 +153,9 @@ runtime.register_kernel(
     "block_rmatvec", "default",
     lambda a_blocks, y_blocks: jnp.einsum("jmn,jmk->jnk", a_blocks,
                                           y_blocks))
+
+runtime.register_kernel("kernel_operand", "tpu", _row_major)
+runtime.register_kernel("kernel_operand", "default", lambda a: a)
 
 runtime.register_kernel("ladder_stats", "tpu", ladder_stats)
 runtime.register_kernel("ladder_stats", "gpu", ladder_stats_gpu)
@@ -199,6 +222,13 @@ def block_matvec(a_blocks: Array, x_blocks: Array) -> Array:
 def block_rmatvec(a_blocks: Array, y_blocks: Array) -> Array:
     """Batched adjoint matvec (M, m, nb)^T @ (M, m, K) -> (M, nb, K)."""
     return runtime.kernel("block_rmatvec")(a_blocks, y_blocks)
+
+
+def kernel_operand(a: Array) -> Array:
+    """``a`` laid out as the registry's kernels read it: row-major on TPU
+    (relaid out once if the device stores it otherwise), unchanged
+    elsewhere. Engines apply it to the design matrix on entry."""
+    return runtime.kernel("kernel_operand")(a)
 
 
 def ladder_stats_auto(az: Array, thetas: Array) -> Array:

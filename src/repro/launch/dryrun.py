@@ -35,7 +35,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCH_NAMES, get_config, shape_applicable
 from repro.configs.base import SHAPES, TPU_V5E, ModelConfig, ShapeConfig
 from repro.launch import hlo_cost
-from repro.launch.mesh import make_ctx, make_production_mesh, make_rules
+from repro.launch.mesh import (auto_mesh, make_ctx, make_production_mesh,
+                               make_rules)
 from repro.models import zoo
 from repro.optim import AdamWConfig, adamw_init, adamw_update
 from repro.sharding import mesh_ctx
@@ -295,8 +296,8 @@ def main() -> None:
     args = ap.parse_args()
 
     if args.smoke:
-        mesh = jax.make_mesh((2, 2), ("data", "model"),
-                             devices=jax.devices()[:4])
+        mesh = auto_mesh((2, 2), ("data", "model"),
+                         devices=jax.devices()[:4])
         for arch in (ARCH_NAMES if not args.arch else [args.arch]):
             rec = smoke_cell(arch, mesh, args.profile)
             print(f"[smoke-ok] {arch}: flops={rec['flops']:.3g} "

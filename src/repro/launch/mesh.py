@@ -7,17 +7,25 @@ must set XLA_FLAGS before the first jax call.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from repro.sharding import MeshCtx
 from repro.sharding.rules import ShardingRules
 
 
+def auto_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with Auto axes. Its default is Explicit axes,
+    which put shardings into array types; the layouts here are written
+    as ``with_sharding_constraint`` hints (``repro.sharding.ctx``), which
+    need Auto axes."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes,
-                         devices=jax.devices()[: _prod(shape)])
+    return auto_mesh(shape, axes, devices=jax.devices()[: _prod(shape)])
 
 
 def _prod(t):

@@ -30,7 +30,7 @@ import os
 import numpy as np
 
 import repro.api as api
-from repro import faults
+from repro import faults, runtime
 
 
 def make_request_data(rng, n: int, m: int, kappa: int):
@@ -84,6 +84,7 @@ def main(argv=None) -> None:
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI-sized run")
     args = ap.parse_args(argv)
+    runtime.enable_compile_cache()
     if args.smoke:
         args.requests, args.clients, args.widths = 8, 4, [8, 12]
 

@@ -21,22 +21,24 @@ string-compares ``jax.default_backend()``:
 * **Platform/XLA configuration** — :func:`set_platform` /
   :func:`jax_enable_x64` / :func:`set_cpu_devices` mirror the bayespec
   config idiom, including the GPU async-collective and latency-hiding
-  scheduler flags.
+  scheduler flags; :func:`enable_compile_cache` places JAX's persistent
+  compilation cache for the command-line entry points.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import os
+import pathlib
 from typing import Callable, Iterator
 
 import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "PRECISION_PRESETS", "PrecisionPolicy", "backend", "check_x64",
-    "escalation_ladder", "force_interpret", "interpret_default",
-    "jax_enable_x64", "kernel", "kernel_table", "ladder_rounds",
+    "F32_DOT", "PRECISION_PRESETS", "PrecisionPolicy", "backend",
+    "check_x64", "enable_compile_cache", "escalation_ladder",
+    "force_interpret", "interpret_default", "jax_enable_x64", "kernel", "kernel_table", "ladder_rounds",
     "precision_name", "register_kernel", "resolve_interpret",
     "resolve_precision", "set_cpu_devices", "set_platform", "use_backend",
     "x64_enabled",
@@ -164,6 +166,15 @@ def force_interpret(enable: bool = True) -> Iterator[None]:
 
 
 # ------------------------------------------------------ precision policy --
+
+# Precision of the solver's own f32 products (the kernels' tiles, inner
+# products, the sharded engine's PCG matvecs, column norms). JAX leaves
+# an f32 dot's precision to the backend, and on TPU the default may run
+# it as one bf16 pass, as a Mosaic kernel's dot does (relative error
+# 2.2e-3 measured on v5e), too coarse for tolerances of 1e-4 (outer) and
+# 1e-6 (PCG). XLA's own f32 GEMV measured exact either way on v5e, and
+# the CPU computes f32 dots in f32, so there it changes nothing.
+F32_DOT = jax.lax.Precision.HIGHEST
 
 _DATA_DTYPES = ("bfloat16", "float16", "float32", "float64")
 _ACCUM_DTYPES = ("float32", "float64")
@@ -345,3 +356,24 @@ def set_cpu_devices(n: int) -> None:
         flags = " ".join(f for f in flags.split()
                          if "xla_force_host_platform_device_count" not in f)
     os.environ["XLA_FLAGS"] = " ".join(filter(None, [flags, flag]))
+
+
+# The checkout root (src/repro/runtime.py -> <checkout>): a fixed cache
+# path, since the path is part of what the cache is found again by.
+_DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (JAX reads it
+    itself; nothing else is set). Otherwise the cache is the fixed
+    ``<checkout>/.jax_cache``. Called by the command-line entry points
+    (``chip_smoke.py``, ``repro.launch.serve``, ``benchmarks/run.py``),
+    never on import and never by tests.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(_DEFAULT_CACHE_DIR))
+    return str(_DEFAULT_CACHE_DIR)

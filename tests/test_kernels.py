@@ -198,3 +198,20 @@ def test_flash_matches_model_chunked_path():
     want = _sdpa_chunked(q, k, v, 64, 64)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=1e-4)
+
+
+# ------------------------------------------------------ operand layout ----
+def test_kernel_operand_relays_out_other_dimension_orders():
+    """The TPU row hands the Mosaic kernels a row-major A (one relayout
+    when the device stores it otherwise); the default row is identity."""
+    from jax.experimental.layout import Format, Layout
+    from repro import runtime
+    a = jax.random.normal(jax.random.PRNGKey(0), (2, 30, 20))
+    at = jax.device_put(a, Format(Layout(major_to_minor=(0, 2, 1)),
+                                  a.sharding))
+    tpu_row = runtime.kernel("kernel_operand", "tpu")
+    got = tpu_row(at)
+    assert tuple(got.format.layout.major_to_minor) == (0, 1, 2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(a))
+    assert tpu_row(a) is a
+    assert runtime.kernel("kernel_operand", "default")(at) is at

@@ -82,14 +82,7 @@ _SUBPROC = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax, jax.numpy as jnp, numpy as np
-    try:  # AxisType landed after jax 0.4.x; plain make_mesh is equivalent
-        from jax.sharding import AxisType
-        def make_mesh(shape, names):
-            return jax.make_mesh(shape, names,
-                                 axis_types=(AxisType.Auto,) * len(shape))
-    except ImportError:
-        def make_mesh(shape, names):
-            return jax.make_mesh(shape, names)
+    from jax import make_mesh
     from repro.core import BiCADMM, BiCADMMConfig
     from repro.core.sharded import ShardedBiCADMM
     from repro.data import SyntheticSpec, make_sparse_regression, \\
