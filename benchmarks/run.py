@@ -7,8 +7,7 @@
 ``--smoke`` runs only the CI-sized benchmarks (projection + x-update
 engines) without touching the committed result baselines.
 
-Each line is ``name,us_per_call,derived``. The roofline section reads the
-dry-run records (benchmarks/results/dryrun_all.json) if present.
+Each line is ``name,us_per_call,derived``.
 """
 from __future__ import annotations
 
@@ -18,7 +17,7 @@ import time
 from repro import runtime
 
 from . import (fig1_convergence, fig23_scaling, fig4_transfer, fleet_bench,
-               gpu_bench, path_sweep, proj_bench, roofline, serve_bench,
+               gpu_bench, path_sweep, proj_bench, serve_bench,
                stream_bench, table1_compare, xupdate_bench)
 
 
@@ -69,8 +68,6 @@ def main() -> None:
     stream_bench.main(full=args.full)
     print("# Backend x precision — proj/xupdate/path")
     gpu_bench.main(full=args.full)
-    print("# Roofline — from dry-run records")
-    roofline.main()
     print(f"# total {time.time() - t0:.1f}s")
 
 

@@ -439,7 +439,8 @@ def _stack(X, y):
     if X.ndim not in (2, 3):
         raise ValueError(f"X must be (samples, n) or (N, m, n); "
                          f"got shape {X.shape}")
-    validate_data(X, y)
+    with runtime.span("repro.validate"):
+        validate_data(X, y)
     if X.ndim == 2:
         X, y = X[None], y.reshape(1, -1)
     return X, y.reshape(X.shape[0], X.shape[1])

@@ -48,6 +48,13 @@ arrays so repeated ``run_from`` calls factorize once.
 
 The distributed (shard_map) engine with identical semantics lives in
 ``repro.core.sharded``; this module is the oracle it is tested against.
+
+Tracing: a fit opens the host spans ``repro.layout``, ``repro.setup``,
+``repro.loop``, ``repro.finalize`` and ``repro.polish``
+(``repro.runtime.SPANS``); the outer loop and the polish run as the
+device programs ``jit_repro_loop`` and ``jit_repro_polish``; the state
+counts the x-update's CG steps (``cg_steps``) and the result carries it
+with the polish's in ``FitResult.counters``.
 """
 from __future__ import annotations
 
@@ -140,6 +147,7 @@ class BiCADMMState(NamedTuple):
     d_r: Array
     b_r: Array
     inner: Any        # SubsolverState pytree stacked over nodes (or None)
+    cg_steps: Array   # () int32 x-update CG steps, over nodes and iterations
 
 
 # Both engines return the engine-agnostic result type; the old name is kept
@@ -155,13 +163,14 @@ def _is_traced(*pytrees) -> bool:
 
 
 def reset_for_resume(st: BiCADMMState) -> BiCADMMState:
-    """Zero the iteration counter and residuals so a (possibly converged)
-    state re-enters the while-loop; the iterates (x,u,z,t,s,v) are kept.
+    """Zero the counters and residuals so a (possibly converged) state
+    re-enters the while-loop; the iterates (x,u,z,t,s,v) are kept.
 
     Each residual gets its own buffer (no aliasing) so the state stays a
     valid donation argument for the jitted while-loop drivers."""
     dt = st.z.dtype
-    return st._replace(k=jnp.asarray(0), p_r=jnp.asarray(jnp.inf, dt),
+    return st._replace(k=jnp.asarray(0), cg_steps=jnp.zeros((), jnp.int32),
+                       p_r=jnp.asarray(jnp.inf, dt),
                        d_r=jnp.asarray(jnp.inf, dt),
                        b_r=jnp.asarray(jnp.inf, dt))
 
@@ -254,13 +263,20 @@ class BiCADMM:
         # The incoming state pytree is donated, so XLA reuses the iterate
         # buffers (x, u, z, ...) in place instead of copying them — the
         # peak live footprint of a resumed solve is one state, not two.
-        self._run_while_donated = jax.jit(
-            lambda factors, As, bs, params, st0:
-                self._run_while(factors, As, bs, params, st0),
-            donate_argnums=(4,))
+        # The function names name the device programs in a profile.
+        def repro_loop(factors, As, bs, params, st0):
+            return self._run_while(factors, As, bs, params, st0)
+        self._run_while_donated = jax.jit(repro_loop, donate_argnums=(4,))
+
         # the polish runs as one program too: reshaping the node stack to
         # (N*m, n) inside it is free, where an eager reshape copies A.
-        self._polish_jit = jax.jit(self._polish)
+        # ``_polish`` hands its CG steps out through a list, read in the
+        # same trace, so that it keeps returning the coefficients alone.
+        def repro_polish(As, bs, support, z0, params):
+            steps = []
+            x = self._polish(As, bs, support, z0, params, steps)
+            return x, steps[0]
+        self._polish_jit = jax.jit(repro_polish)
 
     def _cast(self, As: Array, bs: Array) -> tuple[Array, Array]:
         """Apply the precision policy's data cast (no-op for data=None)
@@ -269,17 +285,18 @@ class BiCADMM:
         pol = self.cfg.precision
         if _is_traced(As, bs):
             return pol.cast_data(As), pol.cast_data(bs)
-        key = (id(As), id(bs))
-        hit = self._cast_cache.get(key)
-        if hit is None:
-            A2 = self._kernel_operand(pol.cast_data(As))
-            if pol.data is None and A2 is As:
-                return As, bs
-            if len(self._cast_cache) >= self._SETUP_CACHE_MAX:
-                self._cast_cache.pop(next(iter(self._cast_cache)))
-            hit = (As, bs, A2, pol.cast_data(bs))
-            self._cast_cache[key] = hit
-        return hit[2], hit[3]
+        with runtime.span("repro.layout"):
+            key = (id(As), id(bs))
+            hit = self._cast_cache.get(key)
+            if hit is None:
+                A2 = self._kernel_operand(pol.cast_data(As))
+                if pol.data is None and A2 is As:
+                    return As, bs
+                if len(self._cast_cache) >= self._SETUP_CACHE_MAX:
+                    self._cast_cache.pop(next(iter(self._cast_cache)))
+                hit = (As, bs, A2, pol.cast_data(bs))
+                self._cast_cache[key] = hit
+            return hit[2], hit[3]
 
     def _kernel_operand(self, As: Array) -> Array:
         """``kernel_operand(As)``, memoized on A alone: refits on the same
@@ -302,6 +319,10 @@ class BiCADMM:
 
     # -- setup ---------------------------------------------------------------
     def _setup(self, As: Array, bs: Array, *, dynamic_penalties: bool = False):
+        with runtime.span("repro.setup"):
+            return self._setup_factors(As, bs, dynamic_penalties)
+
+    def _setup_factors(self, As: Array, bs: Array, dynamic_penalties: bool):
         cfg = self.cfg
         N, m, n = As.shape
         sigma = 1.0 / (N * cfg.gamma)
@@ -348,7 +369,8 @@ class BiCADMM:
     def _x_update(self, factors, params: SolveParams, As, bs, q, x_prev,
                   inner):
         """q: (N, n*K) prox centers, x_prev: (N, n*K) previous outer
-        iterates (PCG warm start) -> (N, n*K), new inner state."""
+        iterates (PCG warm start) -> (N, n*K), new inner state, CG steps
+        summed over nodes (() int32; 0 for the direct solves)."""
         cfg, loss = self.cfg, self.loss
         N, m, n = As.shape
         K = loss.n_classes
@@ -358,19 +380,23 @@ class BiCADMM:
                 x, st = node_prox_feature_split(
                     loss, f, b, qi.reshape(n, K), cfg.inner_iters, st)
                 return x.reshape(-1), st
-            return jax.vmap(one)(factors, bs, q, inner)
+            x, inner = jax.vmap(one)(factors, bs, q, inner)
+            return x, inner, jnp.zeros((), jnp.int32)
 
         if loss.name == "squared":
             def one(f, qi, xi):
                 return x_solve(f, qi, params.rho_c, params.sigma, x0=xi)
-            return jax.vmap(one)(factors, q, x_prev), inner
+            x, steps = jax.vmap(one)(factors, q, x_prev)
+            return x, inner, jnp.sum(steps, dtype=jnp.int32)
 
         def one(A, b, qi):
             qx = qi.reshape(n, K) if K > 1 else qi
-            x = newton_cg_prox(loss, A, b, qx, params.sigma, params.rho_c,
-                               newton_iters=cfg.newton_iters)
-            return x.reshape(-1)
-        return jax.vmap(one)(As, bs, q), inner
+            x, steps = newton_cg_prox(loss, A, b, qx, params.sigma,
+                                      params.rho_c,
+                                      newton_iters=cfg.newton_iters)
+            return x.reshape(-1), steps
+        x, steps = jax.vmap(one)(As, bs, q)
+        return x, inner, jnp.sum(steps, dtype=jnp.int32)
 
     # -- one iteration ---------------------------------------------------------
     def _step(self, factors, As, bs, params: SolveParams,
@@ -380,8 +406,8 @@ class BiCADMM:
         rho_c, rho_b = params.rho_c, params.rho_b
 
         q = st.z[None] - st.u                              # (N, d)
-        x_new, inner = self._x_update(factors, params, As, bs, q, st.x,
-                                      st.inner)
+        x_new, inner, cg_steps = self._x_update(factors, params, As, bs, q,
+                                                st.x, st.inner)
 
         if cfg.over_relax != 1.0:                          # optional relaxation
             x_eff = cfg.over_relax * x_new + (1.0 - cfg.over_relax) * st.z[None]
@@ -404,7 +430,8 @@ class BiCADMM:
         d_r = jnp.sqrt(float(N)) * rho_c * jnp.linalg.norm(z_new - st.z)
         b_r = jnp.abs(gval)
         return BiCADMMState(x_new, u_new, z_new, t_new, s_new, v_new,
-                            st.k + 1, p_r, d_r, b_r, inner)
+                            st.k + 1, p_r, d_r, b_r, inner,
+                            st.cg_steps + cg_steps)
 
     def _init_state(self, As, bs, n, K) -> BiCADMMState:
         cfg = self.cfg
@@ -428,7 +455,7 @@ class BiCADMM:
             s=jnp.zeros((d,), dt), v=jnp.asarray(0.0, dt),
             k=jnp.asarray(0), p_r=jnp.asarray(jnp.inf, dt),
             d_r=jnp.asarray(jnp.inf, dt), b_r=jnp.asarray(jnp.inf, dt),
-            inner=inner)
+            inner=inner, cg_steps=jnp.zeros((), jnp.int32))
 
     # -- drivers ---------------------------------------------------------------
     def init_state(self, As: Array, bs: Array) -> BiCADMMState:
@@ -528,16 +555,18 @@ class BiCADMM:
         factors, N, n, K = self._setup(As, bs, dynamic_penalties=dyn)
         params = self._make_params(N, kappa=kappa, gamma=gamma, rho_c=rho_c)
         st0 = reset_for_resume(state)
-        if _is_traced(As, bs, st0):
-            # Inside an outer trace (vmap/jit/scan — e.g. the sparsify
-            # path vmaps whole fits): the state leaves are tracers, which
-            # cannot be donated — the jitted donating driver would emit
-            # "Some donated buffers were not usable" UserWarnings on every
-            # call. Inline the while-loop into the enclosing trace
-            # instead; the outer jit owns buffer reuse there.
-            st = self._run_while(factors, As, bs, params, st0)
-        else:
-            st = self._run_while_donated(factors, As, bs, params, st0)
+        with runtime.span("repro.loop"):
+            if _is_traced(As, bs, st0):
+                # Inside an outer trace (vmap/jit/scan — e.g. the sparsify
+                # path vmaps whole fits): the state leaves are tracers,
+                # which cannot be donated — the jitted donating driver
+                # would emit "Some donated buffers were not usable"
+                # UserWarnings on every call. Inline the while-loop into
+                # the enclosing trace instead; the outer jit owns buffer
+                # reuse there.
+                st = self._run_while(factors, As, bs, params, st0)
+            else:
+                st = self._run_while_donated(factors, As, bs, params, st0)
         return self._finalize(As, bs, st, params, history=None)
 
     def fit(self, As: Array, bs: Array) -> BiCADMMResult:
@@ -565,22 +594,27 @@ class BiCADMM:
     def _finalize(self, As, bs, st: BiCADMMState, params: SolveParams,
                   history) -> FitResult:
         cfg = self.cfg
-        z_sparse = bilinear.hard_threshold(st.z, params.kappa)
-        support = jnp.abs(z_sparse) > 0
-        if cfg.polish:
-            x_final = self._polish_jit(As, bs, support, z_sparse, params)
-        else:
-            x_final = z_sparse
-        coef = x_final.reshape(As.shape[2], self.loss.n_classes)
-        status = classify_status(st.k, st.p_r, st.d_r, st.b_r,
-                                 tol=cfg.tol,
-                                 divergence_tol=cfg.divergence_tol)
+        with runtime.span("repro.finalize"):
+            z_sparse = bilinear.hard_threshold(st.z, params.kappa)
+            support = jnp.abs(z_sparse) > 0
+            if cfg.polish:
+                with runtime.span("repro.polish"):
+                    x_final, polish_steps = self._polish_jit(
+                        As, bs, support, z_sparse, params)
+            else:
+                x_final, polish_steps = z_sparse, jnp.zeros((), jnp.int32)
+            coef = x_final.reshape(As.shape[2], self.loss.n_classes)
+            status = classify_status(st.k, st.p_r, st.d_r, st.b_r,
+                                     tol=cfg.tol,
+                                     divergence_tol=cfg.divergence_tol)
         return FitResult(coef, st.z, support, st.k,
                          st.p_r, st.d_r, st.b_r, history, st,
-                         status=status)
+                         status=status,
+                         counters={"xupdate_cg_steps": st.cg_steps,
+                                   "polish_cg_steps": polish_steps})
 
     def _polish(self, As, bs, support: Array, z0: Array,
-                params: SolveParams) -> Array:
+                params: SolveParams, steps: list | None = None) -> Array:
         """Debias: re-fit restricted to the recovered support (masked ridge).
 
         Implemented as the full regularized problem plus a large quadratic
@@ -589,9 +623,16 @@ class BiCADMM:
         n x n Gram is small (the ``dense`` x-solver regime); beyond that
         the solve is matrix-free Jacobi-PCG on (A^T A + diag(pen + sigma)),
         warm-started at the thresholded iterate — no n x n array exists
-        anywhere in a large-d fit.
+        anywhere in a large-d fit. ``steps``, where given, receives the
+        solve's CG steps (() int32).
         """
         cfg, loss = self.cfg, self.loss
+
+        def done(x, k):
+            if steps is not None:
+                steps.append(jnp.asarray(k, jnp.int32))
+            return jnp.where(support, x, 0.0)
+
         N, m, n = As.shape
         K = loss.n_classes
         sigma = N * params.sigma         # full-problem l2 weight = 1 / gamma
@@ -607,13 +648,14 @@ class BiCADMM:
                 H = G + jnp.diag((pen + sigma).astype(acc))
                 x = jnp.linalg.solve(H, rmatvec_auto(A_all, b_all,
                                                      out_dtype=acc))
-                return jnp.where(support, x, 0.0)
+                return done(x, 0)
             shift = pen + sigma
             inv = 1.0 / (prox.col_sumsq(A_all) + shift)
-            x = prox.pcg(lambda p: normal_matvec_auto(A_all, p, shift),
-                         rmatvec_auto(A_all, b_all), z0, lambda r: inv * r,
-                         max(200, 2 * cfg.cg_iters), cfg.cg_tol)
-            return jnp.where(support, x, 0.0)
+            x, k = prox.pcg(
+                lambda p: normal_matvec_auto(A_all, p, shift),
+                rmatvec_auto(A_all, b_all), z0, lambda r: inv * r,
+                max(200, 2 * cfg.cg_iters), cfg.cg_tol)
+            return done(x, k)
 
         # Newton-CG on the masked problem (penalty keeps off-support ~ 0)
         xshape = (n, K) if K > 1 else (n,)
@@ -632,14 +674,14 @@ class BiCADMM:
                              (matvec_auto(A_all, pv),))
             return (rmatvec_auto(A_all, dlg) + sigma * pv).reshape(-1) + pen * p
 
-        from .prox import _cg
-        xf = z0
-
-        def body(_, xf):
+        def body(_, carry):
+            xf, total = carry
             g = obj_grad(xf)
-            return xf - _cg(lambda p: hvp(xf, p), g, 60)
-        xf = jax.lax.fori_loop(0, cfg.newton_iters, body, xf)
-        return jnp.where(support, xf, 0.0)
+            step, k = prox._cg(lambda p: hvp(xf, p), g, 60)
+            return xf - step, total + k
+        xf, total = jax.lax.fori_loop(0, cfg.newton_iters, body,
+                                      (z0, jnp.asarray(0)))
+        return done(xf, total)
 
 
 def fit_sparse_model(loss: str, As: Array, bs: Array, kappa: int,

@@ -153,7 +153,7 @@ def init_fleet_state(solver: BiCADMM, B: int, N: int, n: int,
         s=jnp.zeros((B, d), dt), v=jnp.zeros((B,), dt),
         k=jnp.zeros((B,), jnp.int32), p_r=jnp.full((B,), jnp.inf, dt),
         d_r=jnp.full((B,), jnp.inf, dt), b_r=jnp.full((B,), jnp.inf, dt),
-        inner=None)
+        inner=None, cg_steps=jnp.zeros((B,), jnp.int32))
 
 
 def zero_lane_state(solver: BiCADMM, N: int, n: int, dt) -> BiCADMMState:
@@ -171,11 +171,12 @@ def stack_states(states) -> BiCADMMState:
 
 def reset_fleet_for_resume(st: BiCADMMState) -> BiCADMMState:
     """Batched counterpart of ``bicadmm.reset_for_resume``: zero every
-    lane's counter and residuals (fresh, non-aliased buffers so the state
+    lane's counters and residuals (fresh, non-aliased buffers so the state
     stays donatable), keep the iterates for the warm refit."""
     dt = st.z.dtype
     B = st.z.shape[0]
     return st._replace(k=jnp.zeros((B,), jnp.int32),
+                       cg_steps=jnp.zeros((B,), jnp.int32),
                        p_r=jnp.full((B,), jnp.inf, dt),
                        d_r=jnp.full((B,), jnp.inf, dt),
                        b_r=jnp.full((B,), jnp.inf, dt))

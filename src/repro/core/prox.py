@@ -34,7 +34,9 @@ backend           setup          per-solve   memory       regime
   the outer ADMM loop match the dense oracle (tests/test_xsolver.py).
 
 All backend solves dispatch through :func:`x_solve` on the factor pytree
-type, so the solver loops stay backend-agnostic. The non-squared losses use
+type, so the solver loops stay backend-agnostic; it returns the CG steps
+the solve took with ``x`` (0 for the direct backends), which the solver
+sums into its ``cg_steps`` counter. The non-squared losses use
 ``newton_cg_prox`` — matrix-free guarded Newton-CG whose matvecs route
 through the same kernel layer.
 
@@ -240,9 +242,11 @@ def cg_setup(A: Array, b: Array, iters: int = 200,
 
 def pcg(matvec: Callable[[Array], Array], rhs: Array, x0: Array,
         precond: Callable[[Array], Array], iters: int, tol: float,
-        dot_fn: Callable[[Array, Array], Array] | None = None) -> Array:
+        dot_fn: Callable[[Array, Array], Array] | None = None
+        ) -> tuple[Array, Array]:
     """Preconditioned conjugate gradients, warm-started at ``x0``, with a
     relative-residual stop and fixed max iterations (jit-safe while_loop).
+    Returns ``(x, steps)``: each step is one ``matvec``.
 
     ``dot_fn`` makes the two reductions per iteration injectable: the
     reference engine passes the plain vdot default, ``repro.core.sharded``
@@ -272,23 +276,31 @@ def pcg(matvec: Callable[[Array], Array], rhs: Array, x0: Array,
         *_, rr, k = state
         return (rr > tol2) & (k < iters)
 
-    x, *_ = jax.lax.while_loop(cond, body,
-                               (x0, r0, z0, rz0, dot(r0, r0), jnp.asarray(0)))
-    return x
+    x, *_, k = jax.lax.while_loop(
+        cond, body, (x0, r0, z0, rz0, dot(r0, r0), jnp.asarray(0)))
+    return x, k
 
 
-def pcg_prox(f: CGFactors, q: Array, rho_c: Array | float,
-             sigma: Array | float, x0: Array | None = None) -> Array:
+def pcg_prox_steps(f: CGFactors, q: Array, rho_c: Array | float,
+                   sigma: Array | float, x0: Array | None = None
+                   ) -> tuple[Array, Array]:
     """Matrix-free exact prox: solve (A^T A + c I) x = A^T b + rho_c q by
     Jacobi-PCG, warm-started from the previous outer iterate (``x0``) —
     after the ADMM transient the prox center moves O(step) per iteration,
-    so warm CG needs a handful of matvecs where cold CG needs dozens."""
+    so warm CG needs a handful of matvecs where cold CG needs dozens.
+    Returns ``(x, CG steps)``."""
     c = sigma + rho_c
     rhs = f.Atb + rho_c * q
     inv = 1.0 / (f.diag + c)
     x0 = q if x0 is None else x0
     return pcg(lambda p: normal_matvec_auto(f.A, p, c), rhs, x0,
                lambda r: inv * r, f.iters, f.tol)
+
+
+def pcg_prox(f: CGFactors, q: Array, rho_c: Array | float,
+             sigma: Array | float, x0: Array | None = None) -> Array:
+    """:func:`pcg_prox_steps` without the step count."""
+    return pcg_prox_steps(f, q, rho_c, sigma, x0)[0]
 
 
 # ------------------------------------------------- the unified engine ----
@@ -342,26 +354,25 @@ class NodeProxEngine:
                     else woodbury_setup(A, b, sigma, rho_c))
         return cg_setup(A, b, self.cg_iters, self.cg_tol)
 
-    def solve(self, factors, q: Array, rho_c, sigma,
-              x0: Array | None = None) -> Array:
-        return x_solve(factors, q, rho_c, sigma, x0)
-
 
 def x_solve(factors, q: Array, rho_c: Array | float, sigma: Array | float,
-            x0: Array | None = None) -> Array:
+            x0: Array | None = None) -> tuple[Array, Array]:
     """Backend dispatch on the factor pytree type (vmap-safe: the types
-    survive batching). ``x0`` is the warm start; only PCG consumes it."""
-    if isinstance(factors, RidgeFactors):
-        return ridge_prox_factorized(factors, q, rho_c)
-    if isinstance(factors, EighRidgeFactors):
-        return ridge_prox_eigh(factors, q, rho_c, sigma)
-    if isinstance(factors, WoodburyFactors):
-        return woodbury_prox(factors, q, rho_c)
-    if isinstance(factors, WoodburyEighFactors):
-        return woodbury_prox_eigh(factors, q, rho_c, sigma)
+    survive batching). ``x0`` is the warm start; only PCG consumes it.
+    Returns ``(x, CG steps)``; the direct backends take none."""
     if isinstance(factors, CGFactors):
-        return pcg_prox(factors, q, rho_c, sigma, x0)
-    raise TypeError(f"unknown x-update factor pytree {type(factors)!r}")
+        return pcg_prox_steps(factors, q, rho_c, sigma, x0)
+    if isinstance(factors, RidgeFactors):
+        x = ridge_prox_factorized(factors, q, rho_c)
+    elif isinstance(factors, EighRidgeFactors):
+        x = ridge_prox_eigh(factors, q, rho_c, sigma)
+    elif isinstance(factors, WoodburyFactors):
+        x = woodbury_prox(factors, q, rho_c)
+    elif isinstance(factors, WoodburyEighFactors):
+        x = woodbury_prox_eigh(factors, q, rho_c, sigma)
+    else:
+        raise TypeError(f"unknown x-update factor pytree {type(factors)!r}")
+    return x, jnp.asarray(0)
 
 
 # ------------------------------------------- incremental factor updates ----
@@ -467,8 +478,9 @@ def chol_append(L: Array, M12: Array, M22: Array) -> Array:
 
 # --------------------------------------------------------- newton-cg ----
 def _cg(matvec: Callable[[Array], Array], rhs: Array, iters: int,
-        tol: float = 1e-10) -> Array:
-    """Plain conjugate gradients with fixed max iterations (jit-safe)."""
+        tol: float = 1e-10) -> tuple[Array, Array]:
+    """Plain conjugate gradients with fixed max iterations (jit-safe).
+    Returns ``(x, steps)``: each step is one ``matvec``."""
     x0 = jnp.zeros_like(rhs)
 
     def body(state):
@@ -486,19 +498,20 @@ def _cg(matvec: Callable[[Array], Array], rhs: Array, iters: int,
         return (rs > tol) & (k < iters)
 
     rs0 = jnp.vdot(rhs, rhs, precision=F32_DOT)
-    x, *_ = jax.lax.while_loop(cond, body, (x0, rhs, rhs, rs0,
-                                            jnp.asarray(0)))
-    return x
+    x, *_, k = jax.lax.while_loop(cond, body, (x0, rhs, rhs, rs0,
+                                               jnp.asarray(0)))
+    return x, k
 
 
 def newton_cg_prox(loss: Loss, A: Array, b: Array, q: Array, sigma: float,
                    rho_c: float, newton_iters: int = 15,
-                   cg_iters: int = 50) -> Array:
+                   cg_iters: int = 50) -> tuple[Array, Array]:
     """Matrix-free Newton-CG for argmin_x l(Ax,b) + sigma/2|x|^2 + rho_c/2|x-q|^2.
 
     For multiclass losses x/q are (n, C); pred = A @ x is (m, C). Every
     A-product routes through the kernels layer (tiled Pallas matvec on TPU,
-    the identical plain contraction elsewhere).
+    the identical plain contraction elsewhere). Returns ``(x, CG steps
+    summed over the Newton iterations)``.
     """
     def obj_grad(x):
         pred = matvec_auto(A, x)
@@ -512,20 +525,10 @@ def newton_cg_prox(loss: Loss, A: Array, b: Array, q: Array, sigma: float,
                          (matvec_auto(A, p),))
         return rmatvec_auto(A, dlg) + (sigma + rho_c) * p
 
-    x0 = q
-
-    def body(_, x):
+    def body(_, carry):
+        x, steps = carry
         g = obj_grad(x)
-        step = _cg(lambda p: hvp(x, p), g, cg_iters)
-        return x - step
+        step, k = _cg(lambda p: hvp(x, p), g, cg_iters)
+        return x - step, steps + k
 
-    return jax.lax.fori_loop(0, newton_iters, body, x0)
-
-
-def direct_prox(loss: Loss, A: Array, b: Array, q: Array, sigma: float,
-                rho_c: float, ridge: RidgeFactors | None = None) -> Array:
-    """Dispatch: closed form for squared loss, Newton-CG otherwise."""
-    if loss.name == "squared":
-        assert ridge is not None, "squared loss requires ridge_setup factors"
-        return ridge_prox_factorized(ridge, q, rho_c)
-    return newton_cg_prox(loss, A, b, q, sigma, rho_c)
+    return jax.lax.fori_loop(0, newton_iters, body, (q, jnp.asarray(0)))

@@ -8,8 +8,9 @@ the field names. Both engines now return the same two types:
 * :class:`FitResult`  — one solve. ``coef`` is the final sparse solution in
   the ``(n, K)`` model layout (K = number of classes; K = 1 for the scalar
   losses), ``z`` the pre-threshold consensus iterate on the flat ``(n*K,)``
-  layout the engines iterate in, ``support`` the flat boolean mask, and
-  ``state`` the resumable solver state for warm starts.
+  layout the engines iterate in, ``support`` the flat boolean mask,
+  ``state`` the resumable solver state for warm starts, and ``counters``
+  the reference engine's inner-step counts.
 * :class:`SparsePath` — a stacked hyperparameter sweep (leading axis = grid
   index). ``strategy`` records how the sweep actually executed —
   ``"warm-scan"`` (state carried point to point), ``"cold-scan"``
@@ -105,6 +106,11 @@ class FitResult(NamedTuple):
     state: Any = None     # resumable solver state — warm-start the next solve
     status: Any = None    # () int32 SolveStatus code (None on legacy paths)
     recovery: Any = None  # tuple[RecoveryAttempt, ...] when the ladder ran
+    # {"xupdate_cg_steps", "polish_cg_steps"}: () int32 device scalars, the
+    # x-update's CG steps summed over nodes and outer iterations (Newton-CG:
+    # over its Newton iterations too) and the polish's; None on engines
+    # whose state carries no counter (sharded, fleet, streaming)
+    counters: Any = None
 
     @property
     def x(self) -> Array:

@@ -586,7 +586,7 @@ class ShardedBiCADMM:
             inv = 1.0 / (xfac + c)                         # Jacobi precond
 
             def x_update(x0, nu0, om0, q):
-                xf = prox.pcg(
+                xf, _ = prox.pcg(
                     lambda p: cg_adj(psum_f(cg_fwd(p))) + c * p,
                     Atb + cfg.rho_c * q[:, 0], x0[:, 0],
                     lambda r: inv * r, cfg.cg_iters, cfg.cg_tol,

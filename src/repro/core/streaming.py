@@ -544,8 +544,8 @@ class StreamingBiCADMM:
             return state
         kap = int(kap)
         q = state.z - state.u[0]
-        x_p = prox.x_solve(f_solo, q, params.rho_c, params.sigma,
-                           x0=state.x[0])
+        x_p, _ = prox.x_solve(f_solo, q, params.rho_c, params.sigma,
+                              x0=state.x[0])
         dt = state.z.dtype
         w = (x_p + state.u[0]).astype(dt)
         new_supp = jnp.abs(bilinear.hard_threshold(w, kap)) > 0

@@ -23,6 +23,8 @@ string-compares ``jax.default_backend()``:
   config idiom, including the GPU async-collective and latency-hiding
   scheduler flags; :func:`enable_compile_cache` places JAX's persistent
   compilation cache for the command-line entry points.
+* **Profiler spans** — :data:`SPANS` names the host spans a fit opens on
+  the profiler's timeline; :func:`span` opens one.
 """
 from __future__ import annotations
 
@@ -36,13 +38,33 @@ import jax
 import jax.numpy as jnp
 
 __all__ = [
-    "F32_DOT", "PRECISION_PRESETS", "PrecisionPolicy", "backend",
+    "F32_DOT", "PRECISION_PRESETS", "PrecisionPolicy", "SPANS", "backend",
     "check_x64", "enable_compile_cache", "escalation_ladder",
     "force_interpret", "interpret_default", "jax_enable_x64", "kernel", "kernel_table", "ladder_rounds",
     "precision_name", "register_kernel", "resolve_interpret",
-    "resolve_precision", "set_cpu_devices", "set_platform", "use_backend",
+    "resolve_precision", "set_cpu_devices", "set_platform", "span",
+    "use_backend",
     "x64_enabled",
 ]
+
+
+# ----------------------------------------------------------------- spans --
+
+# The host spans a fit opens, in the order it opens them, on the device
+# trace's clock (``jax.profiler.TraceAnnotation``): the finiteness check
+# of the data, the precision cast and kernel relayout of A, the x-engine
+# set-up, the dispatch of the outer loop (device program ``repro_loop``),
+# and the finalize with the polish dispatch (``repro_polish``) inside it.
+# With no profiler running a span costs about a microsecond.
+SPANS = ("repro.validate", "repro.layout", "repro.setup", "repro.loop",
+         "repro.finalize", "repro.polish")
+
+
+def span(name: str) -> jax.profiler.TraceAnnotation:
+    """The host span ``name``, one of :data:`SPANS`."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}; expected one of {SPANS}")
+    return jax.profiler.TraceAnnotation(name)
 
 
 # --------------------------------------------------------------- backend --
