@@ -11,15 +11,25 @@ of matvecs
 
 plus an axpy. Two Pallas implementations live here:
 
-* **TPU (Mosaic)** — ``matvec`` / ``rmatvec`` / ``normal_matvec``: A is
-  tiled into MXU-aligned VMEM blocks with the reduction axis innermost in
-  the grid, so each f32 output tile stays resident across the whole sweep
-  of the contracted dimension (grid iterations are sequential on TPU).
-  A is read in place — never padded or copied, so a solver loop around
-  these kernels holds no second A in HBM: the grid covers A with cdiv
-  blocks, edge tiles of the output are clipped on write, the ragged edge
-  of the contraction is masked in-kernel, and only the small operand is
-  zero-padded to whole blocks.
+* **TPU (Mosaic)** — ``matvec`` / ``rmatvec`` / ``normal_matvec``, two
+  bodies chosen by the operand's shape. A is read in place in both —
+  never padded or copied, so a solver loop around these kernels holds no
+  second A in HBM: the grid covers A with cdiv blocks, edge tiles of the
+  output are clipped on write, and the ragged edge of the contraction is
+  masked in-kernel.
+
+  - One column (``x`` of shape (n,) or (n, 1)): ``_gemv`` / ``_gemv_t``,
+    a multiply-reduce in f32 on the VPU over ~4 MB tiles of A. Both
+    vectors stay lane-dense in HBM, as (1, n) and (1, m) rows, and are
+    turned between row and column in VMEM. A GEMV does two flops per
+    element of A, so an MXU body would spend its passes on padding: the
+    VPU keeps pace with HBM.
+  - Several columns (K > 1: softmax's classes, the feature-split
+    sub-solver's blocks): ``_matvec`` / ``_rmatvec``, an f32 MXU dot per
+    MXU-aligned tile with the reduction axis innermost in the grid, so
+    each f32 output tile stays resident across the whole sweep of the
+    contracted dimension (grid iterations are sequential on TPU); the
+    operand is zero-padded to whole blocks and 128 lanes.
 * **GPU (Triton)** — ``matvec_gpu`` / ``rmatvec_gpu`` / ``normal_matvec_gpu``:
   Triton grid programs run in *parallel* with no cross-program memory
   ordering, so the TPU accumulation pattern would race. The GPU kernels
@@ -41,6 +51,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import runtime
 
@@ -51,6 +62,13 @@ Array = jax.Array
 # per-core budget, leaving room for double buffering).
 _VMEM_ELEMS = 1 << 20
 _LANE = 128
+# A tile of the one-column bodies: ~4 MB, so the fixed cost of a grid
+# step is a few percent of its DMA, and double-buffered it stays well
+# inside v5e's 16 MB of scoped VMEM. Its rows come in multiples of 128
+# (the vectors are turned by 128 x 128 transposes), at most
+# _GEMV_MAX_ROWS so those turned blocks stay small beside the tile.
+_GEMV_TILE_BYTES = 4 << 20
+_GEMV_MAX_ROWS = 1024
 # Triton's tl.dot needs every dot dimension >= 16, and tile extents must be
 # powers of two (tl.arange constraint).
 _GPU_MIN = 16
@@ -136,6 +154,181 @@ def _accumulate(o_ref, k, last: int, tail: int, dot) -> None:
 
 # ------------------------------------------------------------ TPU (Mosaic) --
 
+def _one_column(v: Array) -> bool:
+    return v.ndim == 1 or v.shape[1] == 1
+
+
+def _gemv_blocks(m: int, n: int, itemsize: int) -> tuple[int, int]:
+    """A tile of the one-column bodies: whole rows (lanes rounded up to
+    128) where 128 of them fit the tile, and as many rows as fit, in
+    multiples of 128. Tiles may reach past A's edge; the kernels mask or
+    clip what lies there."""
+    bn = min(_rup(n, _LANE), _GEMV_TILE_BYTES // (_LANE * itemsize))
+    bm = _GEMV_TILE_BYTES // (bn * itemsize) // _LANE * _LANE
+    return min(bm, _GEMV_MAX_ROWS, _rup(m, _LANE)), bn
+
+
+def _strip_rows(dtype) -> int:
+    """Rows of A one VPU step reads: a whole (8, 128) f32 register, or a
+    packed (16, 128) one of 16-bit data."""
+    return 8 * (4 // jnp.dtype(dtype).itemsize)
+
+
+def _tree_sum(terms: list[Array]) -> Array:
+    """Pairwise sum: a short chain of dependent adds, and less rounding
+    than a running sum."""
+    while len(terms) > 1:
+        pairs = [terms[i] + terms[i + 1] for i in range(0, len(terms) - 1, 2)]
+        terms = pairs + terms[2 * len(pairs):]
+    return terms[0]
+
+
+def _sweep_by_step(k, last: int, full: int, tail: int, sweep) -> None:
+    """``sweep(valid)`` over contraction step ``k``: every step sees
+    ``full`` valid entries but the last of a ragged contraction, which
+    sees ``tail``. The count is static, so the tail's code is its own."""
+    if tail == 0 or last == 0:
+        sweep(tail or full)
+        return
+    pl.when(k != last)(lambda: sweep(full))
+    pl.when(k == last)(lambda: sweep(tail))
+
+
+def _gemv_kernel(a_ref, x_ref, o_ref, acc_ref, *, rows: int, last: int,
+                 tail: int):
+    """w = A x for one (bm, bn) tile on the VPU. Each strip of ``rows``
+    rows multiplies its 128-lane chunks by x's (a row, broadcast over
+    sublanes) and adds them into ``acc_ref`` (bm, 128), resident across
+    the contraction; the last step turns it and sums its lanes into the
+    lane-dense ``o_ref`` (1, bm)."""
+    k = pl.program_id(1)
+    bm, bn = a_ref.shape
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def sweep(valid):
+        full, part = divmod(valid, _LANE)
+        xs = [jnp.broadcast_to(
+                  x_ref[:, pl.ds(c * _LANE, _LANE)].astype(jnp.float32),
+                  (rows, _LANE))
+              for c in range(full + bool(part))]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 1)
+
+        def strip(r, carry):
+            i = pl.multiple_of(r * rows, rows)
+            terms = []
+            for c, xc in enumerate(xs):
+                t = a_ref[pl.ds(i, rows), pl.ds(c * _LANE, _LANE)]
+                t = t.astype(jnp.float32) * xc
+                if c == full:   # the chunk across A's edge
+                    t = jnp.where(lane < part, t, 0.0)
+                terms.append(t)
+            acc_ref[pl.ds(i, rows), :] += _tree_sum(terms)
+            return carry
+
+        jax.lax.fori_loop(0, bm // rows, strip, 0)
+
+    _sweep_by_step(k, last, bn, tail, sweep)
+
+    @pl.when(k == last)
+    def _out():
+        o_ref[...] = jnp.sum(acc_ref[...].T, axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gemv(a: Array, x: Array, *, interpret: bool) -> Array:
+    m, n = a.shape
+    bm, bn = _gemv_blocks(m, n, a.dtype.itemsize)
+    nk = pl.cdiv(n, bn)
+    out = pl.pallas_call(
+        functools.partial(_gemv_kernel, rows=_strip_rows(a.dtype),
+                          last=nk - 1, tail=n % bn),
+        grid=(pl.cdiv(m, bm), nk),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
+                  pl.BlockSpec((1, bn), lambda i, j: (0, j))],
+        out_specs=pl.BlockSpec((1, bm), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((bm, _LANE), jnp.float32)],
+        name="gemv",
+        interpret=interpret,
+    )(a, x.reshape(1, n))
+    return out.reshape((m,) + x.shape[1:])
+
+
+def _gemv_t_kernel(a_ref, y_ref, o_ref, acc_ref, part_ref, ycol_ref, *,
+                   rows: int, last: int, tail: int):
+    """g = A^T y for one (bm, bn) tile on the VPU. y's slice, a row, is
+    turned into a column in ``ycol_ref`` (bm, 128) (row r holds y[r] in
+    every lane); each strip of ``rows`` rows of A, scaled by it, is added
+    into the tile's ``part_ref`` (rows, bn), and that into ``acc_ref``,
+    resident across the contraction (two levels of sums, not one long
+    chain); the last step folds its sublanes into the lane-dense
+    ``o_ref`` (1, bn)."""
+    k = pl.program_id(1)
+    bm, bn = a_ref.shape
+
+    @pl.when(k == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    part_ref[...] = jnp.zeros_like(part_ref)
+    ycol_ref[...] = jnp.broadcast_to(y_ref[...].astype(jnp.float32),
+                                     (_LANE, bm)).T
+
+    def strip(i, valid_rows=None):
+        yc = ycol_ref[pl.ds(i, rows), :]
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANE), 0)
+        for c in range(bn // _LANE):
+            cols = pl.ds(c * _LANE, _LANE)
+            t = a_ref[pl.ds(i, rows), cols].astype(jnp.float32) * yc
+            if valid_rows is not None:   # the strip across A's edge
+                t = jnp.where(row < valid_rows, t, 0.0)
+            part_ref[:, cols] += t
+
+    def sweep(valid):
+        full, part = divmod(valid, rows)
+
+        def body(r, carry):
+            strip(pl.multiple_of(r * rows, rows))
+            return carry
+
+        jax.lax.fori_loop(0, full, body, 0)
+        if part:
+            strip(full * rows, part)
+
+    _sweep_by_step(k, last, bm, tail, sweep)
+    acc_ref[...] += part_ref[...]
+
+    @pl.when(k == last)
+    def _out():
+        o_ref[...] = jnp.sum(acc_ref[...], axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _gemv_t(a: Array, y: Array, *, interpret: bool) -> Array:
+    m, n = a.shape
+    bm, bn = _gemv_blocks(m, n, a.dtype.itemsize)
+    mk = pl.cdiv(m, bm)
+    rows = _strip_rows(a.dtype)
+    out = pl.pallas_call(
+        functools.partial(_gemv_t_kernel, rows=rows, last=mk - 1,
+                          tail=m % bm),
+        grid=(pl.cdiv(n, bn), mk),
+        in_specs=[pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
+                  pl.BlockSpec((1, bm), lambda i, j: (0, j))],
+        out_specs=pl.BlockSpec((1, bn), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((rows, bn), jnp.float32),
+                        pltpu.VMEM((rows, bn), jnp.float32),
+                        pltpu.VMEM((bm, _LANE), jnp.float32)],
+        name="gemv_t",
+        interpret=interpret,
+    )(a, y.reshape(1, m))
+    return out.reshape((n,) + y.shape[1:])
+
+
 def _mv_kernel(a_ref, x_ref, o_ref, *, last: int, tail: int):
     def dot(valid):
         a = a_ref[...]
@@ -150,12 +343,11 @@ def _mv_kernel(a_ref, x_ref, o_ref, *, last: int, tail: int):
 def _matvec(a: Array, x: Array, *, block_m: int, block_n: int,
             interpret: bool) -> Array:
     m, n = a.shape
-    x2, was_1d = _as_2d(x)
-    k = x2.shape[1]
+    k = x.shape[1]
     kp = _rup(k, _LANE)
     bm, bn = _tpu_blocks(block_m, block_n, m, n, kp)
     mi, nk = pl.cdiv(m, bm), pl.cdiv(n, bn)
-    xp = _pad2(x2, bn, kp)
+    xp = _pad2(x, bn, kp)
     out = pl.pallas_call(
         functools.partial(_mv_kernel, last=nk - 1, tail=n % bn),
         grid=(mi, nk),
@@ -165,15 +357,19 @@ def _matvec(a: Array, x: Array, *, block_m: int, block_n: int,
         out_shape=jax.ShapeDtypeStruct((m, kp), jnp.float32),
         interpret=interpret,
     )(a, xp)
-    out = out[:, :k]
-    return out[:, 0] if was_1d else out
+    return out[:, :k]
 
 
 def matvec(a: Array, x: Array, *, block_m: int = 256, block_n: int = 512,
            interpret: bool | None = None) -> Array:
-    """w = a @ x in f32 (TPU/Mosaic). a (m, n); x (n,) or (n, K)."""
+    """w = a @ x in f32 (TPU/Mosaic). a (m, n); x (n,) or (n, K). One
+    column runs the VPU body, whose tile the shape sets; ``block_m`` /
+    ``block_n`` are the MXU body's tile, for K > 1."""
+    interpret = runtime.resolve_interpret(interpret)
+    if _one_column(x):
+        return _gemv(a, x, interpret=interpret)
     return _matvec(a, x, block_m=block_m, block_n=block_n,
-                   interpret=runtime.resolve_interpret(interpret))
+                   interpret=interpret)
 
 
 def _rmv_kernel(a_ref, y_ref, o_ref, *, last: int, tail: int):
@@ -190,12 +386,11 @@ def _rmv_kernel(a_ref, y_ref, o_ref, *, last: int, tail: int):
 def _rmatvec(a: Array, y: Array, *, block_m: int, block_n: int,
              interpret: bool) -> Array:
     m, n = a.shape
-    y2, was_1d = _as_2d(y)
-    k = y2.shape[1]
+    k = y.shape[1]
     kp = _rup(k, _LANE)
     bm, bn = _tpu_blocks(block_m, block_n, m, n, kp)
     ni, mk = pl.cdiv(n, bn), pl.cdiv(m, bm)
-    yp = _pad2(y2, bm, kp)
+    yp = _pad2(y, bm, kp)
     out = pl.pallas_call(
         functools.partial(_rmv_kernel, last=mk - 1, tail=m % bm),
         grid=(ni, mk),
@@ -205,15 +400,19 @@ def _rmatvec(a: Array, y: Array, *, block_m: int, block_n: int,
         out_shape=jax.ShapeDtypeStruct((n, kp), jnp.float32),
         interpret=interpret,
     )(a, yp)
-    out = out[:, :k]
-    return out[:, 0] if was_1d else out
+    return out[:, :k]
 
 
 def rmatvec(a: Array, y: Array, *, block_m: int = 256, block_n: int = 512,
             interpret: bool | None = None) -> Array:
-    """g = a^T @ y in f32 (TPU/Mosaic). a (m, n); y (m,) or (m, K)."""
+    """g = a^T @ y in f32 (TPU/Mosaic). a (m, n); y (m,) or (m, K). One
+    column runs the VPU body; ``block_m`` / ``block_n`` as in
+    :func:`matvec`."""
+    interpret = runtime.resolve_interpret(interpret)
+    if _one_column(y):
+        return _gemv_t(a, y, interpret=interpret)
     return _rmatvec(a, y, block_m=block_m, block_n=block_n,
-                    interpret=runtime.resolve_interpret(interpret))
+                    interpret=interpret)
 
 
 def normal_matvec(a: Array, p: Array, shift: Array | float, *,

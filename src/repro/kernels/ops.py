@@ -8,12 +8,20 @@ kernel registry — no backend string checks live here:
 kernel             tpu (Mosaic)           gpu (Triton)           default
 =================  =====================  =====================  ==========
 gram               tiled resident-tile    per-tile fori_loop     plain jnp
-matvec / rmatvec   tiled resident-tile    per-tile fori_loop     plain jnp
+matvec / rmatvec   1 column: VPU GEMV;    per-tile fori_loop     plain jnp
+                   K > 1: MXU tiles
 normal_matvec      two tiled passes       two tiled passes       plain jnp
 block_(r)matvec    vmapped kernel         vmapped kernel         einsum
 ladder_stats       one-pass (2, B) tile   partial tiles + sum    plain jnp
 flash_attention    compiled kernel        (not ported)           interpret
 =================  =====================  =====================  ==========
+
+On TPU the operand's shape picks the matvec body: a single column
+(x (n,) or (n, 1)) runs the f32 multiply-reduce on the VPU over ~4 MB
+tiles of A, with both vectors lane-dense; several columns (softmax's
+classes, the feature-split sub-solver's (M, nb, K) blocks) run the f32
+MXU dot per tile. ``normal_matvec`` and the block products inherit the
+choice. See ``repro.kernels.matvec``.
 
 The ``default`` column is the bit-identical historical CPU fallback (XLA's
 CPU matmuls need no hand tiling); interpret-mode Pallas is reachable only

@@ -99,6 +99,122 @@ def test_matvec_property(m, n, seed):
                                rtol=1e-3)
 
 
+# ---------------------------------------------- one-column VPU GEMV ----
+def _pallas_names(fn, *args) -> list[str]:
+    """Names of the pallas_calls ``fn`` traces to, nested jaxprs included."""
+    names = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                names.append(str(eqn.params["name"]))
+            for v in eqn.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return names
+
+
+def test_interpret_mode_fills_past_the_edge_with_nan():
+    """The parity cases below rest on this: in interpret mode a block that
+    reaches past an array's edge reads NaN there, so every ragged shape
+    plants NaN past A's edge (and the vectors'), and a kernel that let it
+    into a sum would return NaN."""
+    from jax.experimental import pallas as pl
+
+    def copy(a_ref, o_ref):
+        o_ref[...] = a_ref[...]
+
+    out = pl.pallas_call(
+        copy, grid=(1,),
+        in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
+        out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((8, 128), jnp.float32),
+        interpret=True)(jnp.ones((5, 100), jnp.float32))
+    out = np.asarray(out)
+    assert np.isnan(out[5:]).all() and np.isnan(out[:, 100:]).all()
+    assert (out[:5, :100] == 1).all()
+
+
+# (m, n): ragged both ways; many row blocks with a part strip at the end
+# (25001 = 24 x 1024 + 425); n = 2000 in one block with a part lane chunk;
+# a contraction over n in two blocks (9000 = 8192 + 808)
+_GEMV_SHAPES = [(70, 45), (25001, 130), (1003, 2000), (40, 9000)]
+_GEMV_CASES = (
+    [(op, m, n, col, "float32") for op in ("matvec", "rmatvec")
+     for m, n in _GEMV_SHAPES for col in (False, True)]
+    + [(op, m, n, False, "float32") for op in ("normal_scalar",
+                                               "normal_vector")
+       for m, n in _GEMV_SHAPES]
+    + [(op, 70, 45, True, "float32") for op in ("normal_scalar",
+                                                "normal_vector")]
+    + [(op, m, n, col, "float32") for op in ("block_matvec", "block_rmatvec")
+       for m, n in [(70, 45), (1003, 2000)] for col in (False, True)]
+    # bf16 data: (16, 128) strips, upcast in the kernel, f32 sums
+    + [(op, m, n, False, "bfloat16") for op in ("matvec", "rmatvec")
+       for m, n in [(70, 45), (25001, 130)]])
+
+
+@pytest.mark.parametrize("op,m,n,col,dtype", _GEMV_CASES)
+def test_gemv_vector_path_matches_f64(op, m, n, col, dtype):
+    """The one-column bodies against an f64 NumPy oracle: 1-D and (., 1)
+    operands, ragged m and n (NaN past every edge, see above), the vmapped
+    node-block products, f32 and bf16 data; every case runs the VPU
+    body."""
+    from repro import runtime
+    rng = np.random.default_rng(m * 7 + n)
+    M = 2 if op.startswith("block") else None
+    lead = () if M is None else (M,)
+    a = jnp.asarray(rng.standard_normal(lead + (m, n)), dtype)
+    vec_len = m if op in ("rmatvec", "block_rmatvec") else n
+    v = rng.standard_normal(lead + (vec_len,) + ((1,) if col else ()))
+    v = v.astype(np.float32)
+    a64, v64 = np.asarray(a, np.float64), v.astype(np.float64)
+    if op == "matvec":
+        fn, want = lambda a, v: ops.matvec(a, v, interpret=True), a64 @ v64
+    elif op == "rmatvec":
+        fn, want = lambda a, v: ops.rmatvec(a, v, interpret=True), a64.T @ v64
+    elif op.startswith("normal"):
+        # a scalar penalty, or a per-feature ridge diagonal shaped like p
+        shift = (np.float32(1.5) if op == "normal_scalar" else
+                 np.abs(rng.standard_normal(v.shape)).astype(np.float32) + 0.1)
+        fn = lambda a, v: ops.normal_matvec(a, v, shift, interpret=True)
+        want = a64.T @ (a64 @ v64) + np.float64(shift) * v64
+    else:
+        fn = runtime.kernel(op, "tpu")
+        eq = "jmn,jn...->jm..." if op == "block_matvec" else "jmn,jm...->jn..."
+        want = np.einsum(eq, a64, v64)
+    with runtime.force_interpret(True):
+        got = np.asarray(fn(a, jnp.asarray(v)), np.float64)
+        names = _pallas_names(fn, a, v)
+    assert names and set(names) <= {"gemv", "gemv_t"}
+    assert got.shape == want.shape
+    assert np.isfinite(got).all()
+    err = np.abs(got - want).max() / np.abs(want).max()
+    assert err < 1e-5, err
+
+
+@pytest.mark.parametrize("kernel", ["matvec", "rmatvec"])
+def test_wide_operand_keeps_the_mxu_body(kernel):
+    """K = 4 columns (softmax's classes, the sub-solver's blocks) still
+    run the MXU body; one column runs the VPU one."""
+    a = jax.random.normal(jax.random.PRNGKey(5), (70, 45))
+    vec = 45 if kernel == "matvec" else 70
+    fn = lambda a, v: getattr(ops, kernel)(a, v, interpret=True)
+    wide = jax.random.normal(jax.random.PRNGKey(6), (vec, 4))
+    names = _pallas_names(fn, a, wide)
+    assert len(names) == 1 and names[0] not in ("gemv", "gemv_t")
+    want = ref.matvec_ref(a, wide) if kernel == "matvec" else \
+        ref.rmatvec_ref(a, wide)
+    np.testing.assert_allclose(np.asarray(fn(a, wide)), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
+    one = {"matvec": "gemv", "rmatvec": "gemv_t"}[kernel]
+    assert _pallas_names(fn, a, wide[:, :1]) == [one]
+    assert _pallas_names(fn, a, wide[:, 0]) == [one]
+
+
 # ------------------------------------------------------- ladder stats ----
 @pytest.mark.parametrize("n,B", [
     (100, 8), (4096, 32), (5000, 64), (1, 4),

@@ -80,6 +80,21 @@ def _assert_kernel_reads_a_in_place(compiled, a_elems: int) -> None:
     assert not _big_copies(hlo, a_elems // 4)
 
 
+def _lane_pads(hlo: str, rows: int) -> list[str]:
+    """copy/pad instructions that widen an operand of at least ``rows``
+    rows to 128 lanes: what an MXU body needs of a single column."""
+    out = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = \w+\[([\d,]*)\]\S* (copy|pad)\(",
+                     line)
+        if m:
+            dims = [int(d) for d in m.group(1).split(",") if d]
+            if dims and dims[-1] == 128 and len(dims) > 1 \
+                    and max(dims[:-1]) >= rows:
+                out.append(line.strip()[:120])
+    return out
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_gram_xy_compiles(one_chip, dtype):
     a = _sds((25000, 2000), dtype, one_chip)
@@ -99,6 +114,42 @@ def test_matvec_kernels_compile(one_chip, kernel, m, n):
         fn, v = (lambda a, v: normal_matvec(a, v, 1.5, interpret=False)), (n,)
     c = _compile(fn, a, _sds(v, jnp.float32, one_chip))
     _assert_kernel_reads_a_in_place(c, m * n)
+
+
+@pytest.mark.parametrize("shape", [(4, 75000, 4000), (4, 25000, 2000),
+                                   (2000, 100000)])
+@pytest.mark.parametrize("kernel", ["matvec", "rmatvec", "normal_matvec"])
+def test_gemv_vector_path_compiles(one_chip, kernel, shape):
+    """One column runs the VPU bodies, vmapped over the node blocks as the
+    x-update calls them at both cells' shapes: A read in place, the
+    vectors never widened to 128 lanes, the kernels named for the trace."""
+    *lead, m, n = shape
+    wrap = jax.vmap if lead else (lambda f: f)
+    if kernel == "matvec":
+        fn, v = wrap(lambda a, v: matvec(a, v, interpret=False)), n
+    elif kernel == "rmatvec":
+        fn, v = wrap(lambda a, v: rmatvec(a, v, interpret=False)), m
+    else:
+        fn = wrap(lambda a, v: normal_matvec(a, v, 1.5, interpret=False))
+        v = n
+    c = _compile(fn, _sds(shape, jnp.float32, one_chip),
+                 _sds((*lead, v), jnp.float32, one_chip))
+    _assert_kernel_reads_a_in_place(c, m * n * (lead[0] if lead else 1))
+    hlo = c.as_text()
+    assert not _lane_pads(hlo, min(m, n))
+    want = {"matvec": ["gemv"], "rmatvec": ["gemv_t"],
+            "normal_matvec": ["gemv", "gemv_t"]}[kernel]
+    for name in want:
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", hlo)
+
+
+def test_gemv_bf16_data_compiles(one_chip):
+    """bf16 data on the one-column path: (16, 128) strips upcast in the
+    kernel, f32 operand (the MXU body refused this pair: PERF.md)."""
+    a = _sds((4, 75000, 4000), jnp.bfloat16, one_chip)
+    fn = jax.vmap(lambda a, v: normal_matvec(a, v, 1.5, interpret=False))
+    c = _compile(fn, a, _sds((4, 4000), jnp.float32, one_chip))
+    _assert_kernel_reads_a_in_place(c, 4 * 75000 * 4000)
 
 
 def test_ladder_stats_compiles(one_chip):
@@ -144,6 +195,7 @@ def test_reference_fit_compiles_within_hbm(one_chip, loss, shape):
     assert used < V5E_HBM
     assert mem.temp_size_in_bytes < N * m * n * 4 // 4   # no A-sized temp
     _assert_kernel_reads_a_in_place(c, N * m * n)
+    assert not _lane_pads(c.as_text(), n)
 
 
 def test_table1_polish_compiles_within_hbm(one_chip):
@@ -160,3 +212,4 @@ def test_table1_polish_compiles_within_hbm(one_chip):
     mem = c.memory_analysis()
     assert mem.temp_size_in_bytes < N * m * n * 4 // 4
     _assert_kernel_reads_a_in_place(c, N * m * n)
+    assert not _lane_pads(c.as_text(), n)
