@@ -30,8 +30,9 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(HERE)),
 sys.path.insert(0, HERE)
 
 
-def readings(cfg: dict, seed: int, precision: str) -> dict:
-    """One dataset's readings, with the number-by-number verdict."""
+def readings(cfg: dict, seed: int, precision: str, mesh=None) -> dict:
+    """One dataset's readings, with the number-by-number verdict; over
+    ``mesh`` where the configuration states one, as the cell runs."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -39,9 +40,9 @@ def readings(cfg: dict, seed: int, precision: str) -> dict:
     import harness
     import reference
     cfg = dict(cfg, data_seed=seed)
-    A = data.make_design(seed, cfg)
+    A = harness.make_design(cfg, mesh)
     if precision == "fp32":
-        est = harness.make_estimator(cfg)
+        est = harness.make_estimator(cfg, mesh)
         rec = harness.one_fit(est, A, 0, cfg)
         del est
         gc.collect()
@@ -73,15 +74,17 @@ def main(argv=None) -> int:
     import run
     import harness
     _, cell, cfg, _ = run.load_cell(args.workload)
+    chips = int(cell["chips"])
     try:
-        harness.devices_for(int(cell["chips"]))
+        devs = harness.devices_for(chips)
     except harness.NoChip as e:
         print(f"control.py: {e}; nothing was run", file=sys.stderr)
         return 2
+    mesh = harness.make_mesh(cfg, chips, devs)
     harness.enable_compile_cache()
     rows = []
     for seed in args.seeds:
-        row = readings(cfg, seed, args.precision)
+        row = readings(cfg, seed, args.precision, mesh)
         print(json.dumps(row), flush=True)
         rows.append(row)
     keys = rows[0]["readings"]

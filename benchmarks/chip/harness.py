@@ -2,8 +2,10 @@
 check against the plain reference, and the result line.
 
 Set-up (counted in ``setup_s``, from process start to window start):
-TPU check, compile cache inside the checkout, the configuration's design
-A made on the device from its ``data_seed``, the estimator, and the
+TPU check, compile cache inside the checkout, the configuration's mesh
+where it states one (a cell on more than one chip must), the design A
+made on the device from its ``data_seed`` (over a mesh, each node's rows
+on its own chip), the estimator, and the
 traffic's warm-up fits on responses the window never uses (they compile
 or load every program the window runs and lay A out once).
 
@@ -64,6 +66,7 @@ class RunView:
     trace: object | None
     design_shape: tuple[int, int, int]
     peaks: dict
+    chips: int = 1
 
 
 class CompileCounter:
@@ -101,15 +104,51 @@ def enable_compile_cache(path: str = CACHE_DIR) -> None:
     jax.config.update("jax_compilation_cache_dir", path)
 
 
-def make_estimator(cfg: dict):
+def make_mesh(cfg: dict, chips: int, devs):
+    """The configuration's ``"mesh": {"nodes": .., "feat": ..}`` over
+    ``devs[:chips]``, with Auto axes as the sharded engine takes them;
+    None for a configuration that states none. A cell whose chips differ
+    from the mesh's size, or a cell on several chips with no mesh, is
+    refused: its design would not be spread over the chips it holds."""
+    spec = cfg.get("mesh")
+    if spec is None:
+        if chips != 1:
+            raise ValueError(f"a cell on {chips} chips needs a configuration "
+                             "that states its mesh")
+        return None
+    sizes = (int(spec["nodes"]), int(spec["feat"]))
+    if sizes[0] * sizes[1] != chips:
+        raise ValueError(f"the mesh {spec} spans {sizes[0] * sizes[1]} "
+                         f"chips, the cell asks for {chips}")
+    import jax
+    from jax.sharding import AxisType
+    return jax.make_mesh(sizes, ("nodes", "feat"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=devs[:chips])
+
+
+def make_design(cfg: dict, mesh=None):
+    """The configuration's design A on the device: over ``mesh``, each
+    node's rows drawn on its own chip."""
+    import data
+    sharding = None
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sharding = NamedSharding(mesh, P("nodes"))
+    return data.make_design(cfg["data_seed"], cfg, sharding)
+
+
+def make_estimator(cfg: dict, mesh=None):
     """The configuration's estimator, through the public API, in the
-    default fp32 precision the configuration states."""
+    default fp32 precision the configuration states; over ``mesh``
+    where the configuration states one."""
     import data
     from repro import api
     cls = {"squared": api.SparseLinearRegression,
            "logistic": api.SparseLogisticRegression}[cfg["loss"]]
+    extra = {} if mesh is None else {"mesh": mesh}
     return cls(data.kappa(cfg), **cfg["solver"],
-               options=api.SolverOptions(**cfg["options"]))
+               options=api.SolverOptions(**cfg["options"], **extra))
 
 
 def one_fit(est, A, k: int, cfg: dict) -> FitRecord:
@@ -164,10 +203,11 @@ def traced(ses: Session, loop, mix: dict, seed: int):
 
 def compare(cfg: dict, A, fits: list[FitRecord]) -> dict:
     """Readings of every fit against the plain reference: the largest
-    support mismatch and coefficient gap over the fits."""
+    support mismatch and coefficient gap over the fits. The Gram is
+    summed from each chip's rows, so A stays where it lies."""
     import data
     import reference
-    G = reference._gram(A) if cfg["loss"] == "squared" else None
+    G = reference.gram(A) if cfg["loss"] == "squared" else None
     mismatch, gap = 0, 0.0
     for rec in fits:
         b, _ = data.make_response(A, cfg["data_seed"], rec.k, cfg)
@@ -231,22 +271,19 @@ def run(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     look for a chip (tests); ``t0`` is the process start on the host
     clock."""
     import jax
-    import data
     import traffic
     chips = int(cell["chips"])
-    if chips != 1:
-        raise ValueError(f"cell {cell['name']} asks for {chips} chips: the "
-                         "harness builds no sharded design yet")
     if devs is None:
         devs = devices_for(chips)
+    mesh = make_mesh(cfg, chips, devs)
     if cache:
         enable_compile_cache()
     devs = devs[:chips]
     loop = traffic.loop(mix)
     peaks = device_peaks(devs[0].device_kind) if trace else None
 
-    A = data.make_design(cfg["data_seed"], cfg)
-    ses = Session(cfg, A, make_estimator(cfg))
+    A = make_design(cfg, mesh)
+    ses = Session(cfg, A, make_estimator(cfg, mesh))
     loop.warm_up(ses, mix)
     counter = CompileCounter()
 
@@ -275,7 +312,7 @@ def run(bench: dict, cell: dict, cfg: dict, mix: dict, seed: int,
     metrics = {}
     if trace:
         view = RunView(cfg, fits, window_s, tr,
-                       tuple(int(s) for s in A.shape), peaks)
+                       tuple(int(s) for s in A.shape), peaks, chips)
         for m in bench["per_layer"]:
             if cell["name"] not in m.get("workloads", [cell["name"]]):
                 continue

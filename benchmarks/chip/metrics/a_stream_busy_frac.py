@@ -12,7 +12,7 @@ def read(view):
     for d in tr.devices:
         busy = tr.busy_s(d)
         a_ops = [(op.start_ns, op.end_ns) for op in tr.device_ops(d)
-                 if tracefile.a_bytes(op, view.design_shape)]
+                 if tracefile.a_bytes(op, view.design_shape, view.chips)]
         if busy > 0 and a_ops:
             a_busy = sum(b - a for a, b in tracefile.union(a_ops)) * 1e-9
             fracs.append(a_busy / busy)

@@ -18,7 +18,7 @@ def read(view):
     for d in tr.devices:
         nbytes, secs = 0, 0.0
         for op in tr.device_ops(d):
-            b = tracefile.a_bytes(op, view.design_shape)
+            b = tracefile.a_bytes(op, view.design_shape, view.chips)
             if b:
                 nbytes += b
                 secs += op.dur_ns * 1e-9

@@ -16,6 +16,6 @@ def read(view):
         return None
     passes = [sum(1 for o in tr.device_ops(d)
                   if tr.module_of(o) == phases.LOOP
-                  and tracefile.a_bytes(o, view.design_shape))
+                  and tracefile.a_bytes(o, view.design_shape, view.chips))
               for d in tr.devices]
     return sum(passes) / len(passes) / iters

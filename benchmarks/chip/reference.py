@@ -16,8 +16,12 @@ with a margin of two orders of magnitude, so the restricted optimum of
 step 3 is the answer a converged Bi-cADMM fit has to reproduce.
 
 Products run node by node over the (N, m, n) design, so no copy of A is
-made. Logistic fits use Newton's method with a backtracking line search
-that sums the objective's change row by row (``_change``).
+made. Where A's nodes lie on several chips, each chip forms the Gram
+A_i^T A_i and A_i^T b_i of its own rows, and only those (n, n) and (n,)
+sums travel, to the chip of the first node, where the one (n, n) solve
+runs: A is never gathered. Logistic fits use Newton's method with a
+backtracking line search that sums the objective's change row by row
+(``_change``).
 """
 from __future__ import annotations
 
@@ -48,6 +52,44 @@ def _rmatvec(A, b):
     return jnp.einsum("imn,im->n", A, b, precision=HI)
 
 
+def _node_blocks(x) -> list:
+    """The shards of ``x`` that hold distinct ranges of nodes (its leading
+    axis), in node order: one for an array on one chip."""
+    blocks = {}
+    for s in x.addressable_shards:
+        if any(sl != slice(None) for sl in s.index[1:]):
+            raise ValueError("the reference takes arrays split by nodes "
+                             f"only, got a shard at {s.index}")
+        blocks.setdefault(s.index[0].start or 0, s)
+    return [blocks[k] for k in sorted(blocks)]
+
+
+def _summed(parts: list, device):
+    """The sum, in order, of per-chip results, each moved to ``device``."""
+    total = None
+    for p in parts:
+        p = jax.device_put(p, device)
+        total = p if total is None else total + p
+    return total
+
+
+def gram(A):
+    """A^T A, each chip's nodes summed on that chip (``_gram``), then the
+    chips' (n, n) sums on the first node's chip."""
+    blocks = _node_blocks(A)
+    return _summed([_gram(s.data) for s in blocks], blocks[0].device)
+
+
+def rmatvec(A, b):
+    """A^T b, each chip's rows on that chip, summed as ``gram`` sums."""
+    a_blocks, b_blocks = _node_blocks(A), _node_blocks(b)
+    if [s.index[0] for s in a_blocks] != [s.index[0] for s in b_blocks]:
+        raise ValueError("A and b are split over different nodes")
+    return _summed([_rmatvec(sa.data, jax.device_put(sb.data, sa.device))
+                     for sa, sb in zip(a_blocks, b_blocks)],
+                    a_blocks[0].device)
+
+
 @jax.jit
 def _matvec(A, x):
     return jnp.einsum("imn,n->im", A, x, precision=HI)
@@ -68,9 +110,9 @@ def _ridge(G, c, inv_gamma, kappa: int):
 
 def squared(A, b, gamma: float, kappa: int, G=None):
     """(coef (n,), support (n,) bool) of the best-subset ridge fit.
-    ``G`` = A^T A may be passed in, as it depends on A alone."""
-    G = _gram(A) if G is None else G
-    coef, idx = _ridge(G, _rmatvec(A, b), 1.0 / gamma, kappa)
+    ``G`` = A^T A (``gram``) may be passed in, as it depends on A alone."""
+    G = gram(A) if G is None else G
+    coef, idx = _ridge(G, rmatvec(A, b), 1.0 / gamma, kappa)
     support = np.zeros(A.shape[2], bool)
     support[np.asarray(idx)] = True
     return np.asarray(coef), support
@@ -126,7 +168,9 @@ def _newton(A, b, x0, mask, inv_gamma, iters: int):
 
 def logistic(A, b, gamma: float, kappa: int):
     """(coef (n,), support (n,) bool) of the best-subset ridge-logistic
-    fit."""
+    fit, of an A on one chip."""
+    if len(_node_blocks(A)) > 1:
+        raise ValueError("the logistic reference takes an A on one chip")
     n = A.shape[2]
     inv_gamma = jnp.float32(1.0 / gamma)
     ones = jnp.ones((n,), jnp.float32)

@@ -8,8 +8,8 @@ import pytest
 
 import harness
 import tracefile
-from metrics import (a_stream_busy_frac, a_stream_roofline, device_idle_frac,
-                     iter_ms, outer_iters)
+from metrics import (a_stream_busy_frac, a_stream_roofline, collective_frac,
+                     device_idle_frac, iter_ms, outer_iters)
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 PEAKS = {"hbm_bytes_per_s": 819e9}
@@ -178,3 +178,82 @@ def test_loops_are_not_ops():
     assert tracefile.opcode(loop) == "while"
     assert tracefile.opcode(MOSAIC) == "custom-call"
     assert tracefile.a_bytes(op(0, 1, loop), SHAPE) == 0
+
+
+ALL_REDUCE_START = ("%all-reduce-start.1 = f32[256]{0} all-reduce-start("
+                    "f32[256]{0} %p0), replica_groups={{0,1,2,3}}, "
+                    "to_apply=%add")
+ALL_REDUCE_DONE = ("%all-reduce-done.1 = f32[256]{0} all-reduce-done("
+                   "f32[256]{0} %all-reduce-start.1)")
+ALL_REDUCE = ("%all-reduce.7 = f32[] all-reduce(f32[] %p1), "
+              "replica_groups={{0,1,2,3}}, to_apply=%add")
+# named like a collective, but a fusion and an add: not exchanges
+NAMED_ONLY = ("%all-reduce.fusion.2 = f32[256]{0} fusion(f32[256]{0} %a), "
+              "kind=kLoop, calls=%fused_computation.2")
+ADD_NAMED = "%all-gather.9 = f32[256]{0} add(f32[256]{0} %a, f32[256]{0} %b)"
+
+
+def test_collectives_found_by_opcode_not_name():
+    for text in (ALL_REDUCE_START, ALL_REDUCE_DONE, ALL_REDUCE):
+        assert tracefile.is_collective(op(0, 1, text)), text
+    for text in (NAMED_ONLY, ADD_NAMED, VECTOR, MOSAIC):
+        assert not tracefile.is_collective(op(0, 1, text)), text
+
+
+def test_collective_frac_is_the_union_over_busy_then_the_mean():
+    """Device 0: collectives over 0-150 (two overlapping halves, counted
+    once) of 300 busy; device 1: 20 of 200 busy, beside a fusion and an
+    add named like collectives that are not counted."""
+    tr = tracefile.Trace(
+        [op(0, 100, ALL_REDUCE_START), op(50, 100, ALL_REDUCE_DONE),
+         op(150, 150, VECTOR),
+         op(0, 20, ALL_REDUCE, device=1), op(20, 100, NAMED_ONLY, device=1),
+         op(120, 80, ADD_NAMED, device=1)],
+        [tracefile.Span("fit", 0, 1000)])
+    assert collective_frac.read(view(tr)) == pytest.approx(
+        (150 / 300 + 20 / 200) / 2)
+
+
+def test_collective_frac_reads_nothing_without_collectives():
+    tr = tracefile.Trace([op(0, 100, VECTOR), op(100, 100, MOSAIC)],
+                         [tracefile.Span("fit", 0, 1000)])
+    assert collective_frac.read(view(tr)) is None
+    assert collective_frac.read(view(None)) is None
+
+
+def test_a_shard_counts_only_over_several_chips():
+    """One chip's shard of the nodes, (N / chips, m, n) or transposed, is
+    A only when the cell runs over several chips: on one chip a
+    (1, m, n) operand is no part of A's layout."""
+    N, m, n = SHAPE
+    shard = ("%fusion.4 = f32[1,1000]{1,0} fusion(f32[1,1000,256]{2,1,0} "
+             "%p0, f32[256]{0} %p1), kind=kLoop")
+    shard_t = ("%fusion.5 = f32[1,256]{1,0} fusion(f32[1,256,1000]{2,1,0} "
+               "%p0, f32[1000]{0} %p1), kind=kLoop")
+    assert (1, m, n) not in tracefile.a_shapes(SHAPE)
+    assert {(1, m, n), (1, n, m)} <= tracefile.a_shapes(SHAPE, chips=4)
+    for text in (shard, shard_t):
+        assert tracefile.a_bytes(op(0, 1, text), SHAPE) == 0
+        assert tracefile.a_bytes(op(0, 1, text), SHAPE, 4) == NODE_BYTES
+    assert tracefile.a_bytes(op(0, 1, MOSAIC), SHAPE, 4) == A_BYTES
+    tr = tracefile.Trace([op(0, 1000, shard, device=d) for d in range(4)],
+                         [tracefile.Span("fit", 0, 2000)])
+    four = harness.RunView({}, [], 2.0, tr, SHAPE, PEAKS, 4)
+    assert a_stream_roofline.read(four) == pytest.approx(
+        100 * NODE_BYTES / 1e-6 / 819e9)
+    assert a_stream_roofline.read(view(tr)) is None
+
+
+@pytest.mark.parametrize("name,roofline,busy", [
+    ("small_trace", 48.99319270864169, 0.12620523389891192),
+    ("program_trace", 48.9941994104884, 0.12616442090545196)])
+def test_recorded_one_chip_readings_unchanged(name, roofline, busy):
+    """The one-chip readers give, on the recorded traces, the values they
+    gave before the readers learned of chips' shards."""
+    with open(os.path.join(DATA, f"{name}.json")) as f:
+        meta = json.load(f)
+    tr = tracefile.load(os.path.join(DATA, f"{name}.xplane.pb"))
+    v = harness.RunView({}, [], tr.window_s, tr, tuple(meta["design_shape"]),
+                        {"hbm_bytes_per_s": 819e9})
+    assert a_stream_roofline.read(v) == roofline
+    assert a_stream_busy_frac.read(v) == busy

@@ -10,9 +10,12 @@ metrics read: device ops with their HLO text, and the harness's host spans.
 * The traced window runs from the first span's start to the last span's
   end; busy and idle are measured inside it.
 * An op reads the design A when one of its operands has A's shape, a
-  node's (m, n) block, or either transposed. The reduction reads this
-  from the op's HLO text and never from a kernel's name, so a Mosaic
+  node's (m, n) block, or either transposed; over several chips also one
+  chip's shard (N / chips, m, n) or its transpose. The reduction reads
+  this from the op's HLO text and never from a kernel's name, so a Mosaic
   custom call and an XLA fusion over the same A read the same bytes.
+* An op is a collective by its HLO opcode (``COLLECTIVES``), never by
+  its name.
 """
 from __future__ import annotations
 
@@ -31,6 +34,12 @@ SHAPE = re.compile(r"^(pred|s8|u8|s16|u16|s32|u32|s64|u64|bf16|f16|f32|f64)"
                    r"\[([\d,]*)\](\{[^}]*\})?")
 # a layout's memory space: S(1) and up are on-chip memories, not HBM
 ON_CHIP = re.compile(r"S\(([1-9]\d*)\)")
+# exchanges between chips, by opcode, with their asynchronous halves
+COLLECTIVES = frozenset(
+    op + part
+    for op in ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
+    for part in ("", "-start", "-done"))
 DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
                "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
                "f64": 8}
@@ -206,24 +215,33 @@ def op_label(text: str) -> str:
     return f"{text[:eq]} {opcode(text)} {result[:60]}".strip()
 
 
-def a_shapes(design_shape) -> set[tuple[int, ...]]:
+def is_collective(op: Op) -> bool:
+    return opcode(op.text) in COLLECTIVES
+
+
+def a_shapes(design_shape, chips: int = 1) -> set[tuple[int, ...]]:
     """The operand shapes that hold all of A or one node's block of it,
-    in either order of the two matrix dimensions."""
+    in either order of the two matrix dimensions; over several chips also
+    one chip's shard of the nodes."""
     N, m, n = design_shape
-    return {(N, m, n), (N, n, m), (m, n), (n, m), (N * m, n), (n, N * m)}
+    shapes = {(N, m, n), (N, n, m), (m, n), (n, m), (N * m, n), (n, N * m)}
+    if chips > 1:
+        shapes |= {(N // chips, m, n), (N // chips, n, m)}
+    return shapes
 
 
-def a_bytes(op: Op, design_shape) -> int:
+def a_bytes(op: Op, design_shape, chips: int = 1) -> int:
     """Bytes of A that ``op`` reads from HBM: the size of each of its
     A-shaped operands (0 where it has none). An A-shaped operand that the
     compiler placed in on-chip memory (a small A, prefetched whole) is
     not read from HBM and is not counted."""
-    return _a_bytes(op.text, tuple(design_shape))
+    return _a_bytes(op.text, tuple(design_shape), chips)
 
 
 @functools.lru_cache(maxsize=None)
-def _a_bytes(text: str, design_shape: tuple[int, int, int]) -> int:
-    want = a_shapes(design_shape)
+def _a_bytes(text: str, design_shape: tuple[int, int, int],
+             chips: int) -> int:
+    want = a_shapes(design_shape, chips)
     total = 0
     for dt, dims in operand_shapes(text, hbm_only=True):
         if dims in want:
