@@ -27,7 +27,9 @@ fit) and ``compile_s`` (what the cold fit took on top of it).
 
 ``--four-chips`` runs only the Table-1 squared fit through
 ``engine="sharded"`` on a (nodes=4, feat=1) mesh, each node's rows on its
-own chip, and the one-chip fit of the same data to compare with.
+own chip, and the one-chip fit of the same data to compare with: both
+polished, so their supports must agree and their coefficients too, within
+``COEF_GAP`` of the largest.
 
 The last line of standard output is one JSON object,
 ``{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}``,
@@ -60,6 +62,9 @@ DENSE = dict(n_nodes=4, m_per_node=25_000, n_features=2_000,
 LOGISTIC = dict(n_nodes=4, m_per_node=25_000, n_features=2_000,
                 sparsity_level=0.9)
 SERVE = dict(requests=32, clients=8, widths=[256, 512])
+# largest |sharded - one-chip| coefficient over the largest one-chip one:
+# the table1_squared cell's own coef_gap limit (benchmarks/chip/configs)
+COEF_GAP = 3e-5
 
 
 def log(msg: str) -> None:
@@ -277,13 +282,16 @@ def phase_four_chips(seed: int) -> None:
     log(f"[4 chips] one chip: engine={one.engine_} {describe(ro)} "
         f"compile_s={oc_s:.2f} fit_s={of_s:.2f}")
     same = bool(np.array_equal(np.asarray(r.support), np.asarray(ro.support)))
+    coef_s, coef_o = np.asarray(r.coef), np.asarray(ro.coef)
+    gap = float(np.max(np.abs(coef_s - coef_o)) / np.max(np.abs(coef_o)))
     log(f"[4 chips] support: {int(np.asarray(r.support).sum())} nonzeros, "
-        f"equal={same}")
+        f"equal={same}; coef gap {gap:.3e} (limit {COEF_GAP:g})")
     assert sharded.engine_ == "sharded", "the sharded engine did not run"
     assert status_name(r) == "CONVERGED", f"sharded ended {status_name(r)}"
     assert status_name(ro) == "CONVERGED", \
         f"one-chip fit ended {status_name(ro)}"
     assert same, "sharded support differs from the one-chip fit"
+    assert gap <= COEF_GAP, "sharded coefficients differ from the one-chip fit"
 
 
 def main(argv=None) -> int:

@@ -283,6 +283,13 @@ class Capabilities:
     runs a sequential cold scan with a shared compile (``"cold-scan"``,
     identical numerics, no cross-point batching). The executed strategy is
     also recorded on every returned :class:`SparsePath`.
+
+    Both engines honour ``SolverOptions(polish=True)``, the default: the
+    coefficients are re-fit on the recovered support. The reference
+    engine polishes every loss; the sharded engine polishes the squared
+    and logistic losses (a kappa x kappa system summed over its nodes) and
+    returns the thresholded consensus iterate for softmax and smoothed
+    hinge.
     """
     engine: str
     distributed: bool          # runs under shard_map on a device mesh
@@ -498,8 +505,9 @@ class _ReferenceAdapter:
 
 
 class _ShardedAdapter:
-    """The shard_map production engine behind the uniform surface. Data is
-    re-flattened to the (N*m, n) row layout its mesh shards."""
+    """The shard_map production engine behind the uniform surface. The
+    (N, m, n) node stack goes to the engine as the caller holds it: the
+    engine shards its leading axis over `nodes` without a copy."""
     name = "sharded"
 
     def __init__(self, problem: SparseProblem, options: SolverOptions):
@@ -512,11 +520,6 @@ class _ShardedAdapter:
             projection=options.sharded_projection,
             x_update=options.x_update)
 
-    @staticmethod
-    def _flat(As, bs):
-        N, m, n = As.shape
-        return As.reshape(N * m, n), bs.reshape(-1)
-
     def fit(self, As, bs, *, kappa=None, gamma=None, rho_c=None,
             state=None, **kw) -> FitResult:
         """One sharded solve (no per-solve hyperparameter overrides)."""
@@ -526,15 +529,13 @@ class _ShardedAdapter:
                 "the sharded engine (Capabilities.per_solve_overrides="
                 "False): penalties are baked into its cached per-device "
                 "factors — use fit_path for kappa sweeps, or a new problem")
-        A, b = self._flat(As, bs)
-        return self.solver.fit(A, b, state=state, **kw)
+        return self.solver.fit(As, bs, state=state, **kw)
 
     def fit_path(self, As, bs, kappas, *, gammas=None, rho_cs=None,
                  warm_start=True, **kw) -> SparsePath:
         """Warm-started kappa path: one shard_map + scan call."""
         _check_sweep(self.caps, gammas, rho_cs)
-        A, b = self._flat(As, bs)
-        return self.solver.fit_path(A, b, kappas, warm_start=warm_start,
+        return self.solver.fit_path(As, bs, kappas, warm_start=warm_start,
                                     **kw)
 
     def fit_grid(self, As, bs, kappas, *, gammas=None, rho_cs=None
@@ -544,8 +545,7 @@ class _ShardedAdapter:
         scan (shared compile, identical numerics), and the returned path
         says so in ``.strategy`` ("cold-scan")."""
         _check_sweep(self.caps, gammas, rho_cs)
-        A, b = self._flat(As, bs)
-        return self.solver.fit_path(A, b, kappas, warm_start=False)
+        return self.solver.fit_path(As, bs, kappas, warm_start=False)
 
     def fit_many_stacked(self, As, bs, **kw) -> FleetResult:
         """Fleet fitting is a reference-engine capability: the sharded

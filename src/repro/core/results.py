@@ -108,8 +108,10 @@ class FitResult(NamedTuple):
     recovery: Any = None  # tuple[RecoveryAttempt, ...] when the ladder ran
     # {"xupdate_cg_steps", "polish_cg_steps"}: () int32 device scalars, the
     # x-update's CG steps summed over nodes and outer iterations (Newton-CG:
-    # over its Newton iterations too) and the polish's; None on engines
-    # whose state carries no counter (sharded, fleet, streaming)
+    # over its Newton iterations too) and the polish's; on the sharded
+    # engine the x-update's are the slowest node's and the polish's are its
+    # Newton steps; None on engines whose state carries no counter (fleet,
+    # streaming)
     counters: Any = None
 
     @property

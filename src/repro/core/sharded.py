@@ -89,10 +89,34 @@ Warm starts are first-class, mirroring ``repro.core.bicadmm``:
 
 The semantics are tested for exact agreement with ``repro.core.bicadmm``
 (single-process oracle) in ``tests/test_sharded.py`` / ``tests/test_path.py``.
+
+Data layout, polish and tracing
+-------------------------------
+* ``fit`` / ``fit_path`` take A as (N*m, n) rows or as the node stack
+  (G, m, n) the estimators hold, G a multiple of the `nodes` size. The
+  stack is sharded on its leading axis as it lies and a device flattens its
+  (G/N, m, n_j) block inside the programs, so a fit makes no copy of A. The
+  layout and the x-update factors are cached on the caller's array, one
+  entry.
+* With ``cfg.polish`` (the default) squared and logistic fits are debiased
+  on the recovered support S, |S| <= kappa, as the single-process engine
+  debiases them: the program ``repro_polish`` takes A_i[:, S] on each
+  device, psums A_i,S^T D_i A_i,S and A_i,S^T r_i over `nodes` (a
+  kappa x kappa matrix and a kappa-vector) and solves the ridge system by
+  Cholesky on every device — for the squared loss once, refined by one
+  step whose gradient is taken from A_i,S, for the logistic loss in
+  ``cfg.newton_iters`` Newton steps from the thresholded iterate.
+  Softmax and smoothed-hinge fits return the thresholded iterate.
+* ``fit`` opens the host spans ``repro.layout``, ``repro.setup``,
+  ``repro.loop``, ``repro.finalize`` and ``repro.polish``
+  (``repro.runtime.SPANS``); its programs are ``jit_repro_loop`` and
+  ``jit_repro_polish``; ``FitResult.counters`` holds the x-update's CG
+  steps of the slowest node and the polish's Newton steps.
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple
 
 import jax
@@ -138,6 +162,7 @@ class ShardedState(NamedTuple):
     p_r: Array
     d_r: Array
     b_r: Array
+    cg: Array       # () int32 this node's x-update CG steps, summed
 
 
 class ShardedGlobalState(NamedTuple):
@@ -257,9 +282,12 @@ def batched_support_skappa(z: Array, kappa: Array | float,
 class ShardedBiCADMM:
     """Bi-cADMM over a ("nodes", "feat") mesh.
 
-    A_global: (N_total_samples, n) — rows sharded over `nodes`, cols over
-    `feat`. b_global: (N_total_samples,) [or int labels]. The number of
-    paper-nodes N equals the `nodes` mesh size; M equals the `feat` size.
+    A_global: (N_total_samples, n), or a node stack (G, m, n), sharded on
+    its leading axis where G is a multiple of the `nodes` size and
+    flattened to rows (a copy) otherwise — rows over `nodes`, cols over
+    `feat`. b_global: (N_total_samples,) or (G, m) [or int labels]. The
+    number of paper-nodes N equals the `nodes` mesh size; M equals the
+    `feat` size.
     """
     loss: Loss | str
     cfg: BiCADMMConfig
@@ -276,8 +304,6 @@ class ShardedBiCADMM:
     # — gather-free under projection="ladder_exact"). "auto" picks cg when
     # the per-device block factor would exceed the dense regime.
     x_update: str = "auto"
-
-    _FACTOR_CACHE_MAX = 4
 
     def __post_init__(self):
         if isinstance(self.loss, str):
@@ -305,36 +331,17 @@ class ShardedBiCADMM:
         # context; baked into this instance's shard_map programs at trace
         # time (the _jit_cache is per instance, so it never leaks).
         self._fault_hook = faults.active_hook(self)
-        # memoized policy data casts (see BiCADMM._cast): stable array ids
-        # keep the id-keyed factor cache below hitting across repeat fits.
-        self._cast_cache: dict = {}
         # jitted shard_map programs, keyed on the python values the closures
         # bake in — reused across calls so repeated fits/sweeps don't
         # re-trace (shapes/dtypes are handled by jit's own cache)
         self._jit_cache: dict = {}
-        # per-data setup factors (per-device Cholesky / CG preconditioner),
-        # keyed on the data array so repeated warm-started fits — the
-        # resumable-state workflow — pay the setup shard_map program once.
-        # Entries hold strong references to the keyed arrays.
+        # A as the programs take it (cast, padded) and its per-device setup
+        # factors (block Cholesky / CG preconditioner), keyed on the
+        # caller's array so repeated fits on one design — new responses,
+        # the resumable-state workflow — lay A out and factor it once. One
+        # entry, holding a strong reference to the keyed array: a new
+        # design replaces it, so at most one prepared copy of A is alive.
         self._factor_cache: dict = {}
-
-    def _cast(self, A_global: Array, b_global: Array) -> tuple[Array, Array]:
-        """Apply the precision policy's data cast (no-op for data=None)."""
-        pol = self.cfg.precision
-        if pol.data is None:
-            return A_global, b_global
-        if isinstance(A_global, jax.core.Tracer) \
-                or isinstance(b_global, jax.core.Tracer):
-            return pol.cast_data(A_global), pol.cast_data(b_global)
-        key = (id(A_global), id(b_global))
-        hit = self._cast_cache.get(key)
-        if hit is None:
-            if len(self._cast_cache) >= self._FACTOR_CACHE_MAX:
-                self._cast_cache.pop(next(iter(self._cast_cache)))
-            hit = (A_global, b_global, pol.cast_data(A_global),
-                   pol.cast_data(b_global))
-            self._cast_cache[key] = hit
-        return hit[2], hit[3]
 
     def _x_mode(self, nb: int) -> str:
         if self.x_update != "auto":
@@ -354,10 +361,38 @@ class ShardedBiCADMM:
         nb = -(-n // M)
         return N, M, nb
 
-    def _pad(self, A: Array, n_pad: int) -> Array:
-        n = A.shape[1]
-        if n_pad != n:
-            A = jnp.pad(A, ((0, 0), (0, n_pad - n)))
+    def _a_spec(self, A: Array) -> P:
+        """A's rows, or a node stack's leading axis, over `nodes`; its
+        columns over `feat`."""
+        return P(self.nodes_axis, *([None] * (A.ndim - 2)), self.feat_axis)
+
+    def _b_spec(self, b: Array) -> P:
+        return P(self.nodes_axis, *([None] * (b.ndim - 1)))
+
+    @staticmethod
+    def _local_rows(A_loc: Array, b_loc: Array | None = None):
+        """A device's block of A as (rows, n_j), and of b as (rows, ...): a
+        node stack's (G/N, m, n_j) block is flattened inside the program,
+        where the reshape of a local block is free."""
+        A_blk = A_loc.reshape(-1, A_loc.shape[-1])
+        if b_loc is None:
+            return A_blk
+        return A_blk, b_loc.reshape(A_blk.shape[:1]
+                                    + b_loc.shape[A_loc.ndim - 1:])
+
+    def _layout(self, A: Array) -> Array:
+        """A as the programs take it: the precision policy's cast, a node
+        stack whose leading axis does not tile `nodes` flattened to rows,
+        and the columns padded to a multiple of the `feat` size. Each step
+        is a copy where it applies; none does for a float32 (N, m, n)
+        stack on an N-node mesh whose `feat` size divides n."""
+        A = self.cfg.precision.cast_data(A)
+        N, M, nb = self._sizes(A.shape[-1])
+        if A.ndim == 3 and A.shape[0] % N:
+            A = A.reshape(-1, A.shape[-1])
+        pad = M * nb - A.shape[-1]
+        if pad:
+            A = jnp.pad(A, [(0, 0)] * (A.ndim - 1) + [(0, pad)])
         return A
 
     # ---- cached setup --------------------------------------------------------
@@ -374,7 +409,8 @@ class ShardedBiCADMM:
         c = sigma + cfg.rho_c
 
         if mode == "cg":
-            def setup_run(A_blk):
+            def setup_run(A_loc):
+                A_blk = self._local_rows(A_loc)
                 # batched-mirrored col_sumsq (unit leading axis): the
                 # reference engine computes it under vmap over nodes, and
                 # batched/unbatched reductions differ at the ulp level
@@ -390,40 +426,41 @@ class ShardedBiCADMM:
                 return colsq[None, None]
             out_specs = P(nodes, feat, None)
         else:
-            def setup_run(A_blk):
+            def setup_run(A_loc):
+                A_blk = self._local_rows(A_loc)
                 acc = prox._accum(A_blk.dtype)
                 G = gram_auto(A_blk, out_dtype=acc)
                 H = cfg.rho_l * G + c * jnp.eye(A_blk.shape[1], dtype=acc)
                 return jnp.linalg.cholesky(H)[None, None]
             out_specs = P(nodes, feat, None, None)
 
-        key = ("setup", n, mode)
+        key = ("setup", n, mode, A_p.ndim)
         if key not in self._jit_cache:
             self._jit_cache[key] = jax.jit(jax.shard_map(
-                setup_run, mesh=self.mesh, in_specs=(P(nodes, feat),),
+                setup_run, mesh=self.mesh,
+                in_specs=(self._a_spec(A_p),),
                 out_specs=out_specs, check_vma=False))
         return self._jit_cache[key](A_p)
 
-    def _prepare(self, A_global: Array, n: int) -> tuple[Array, Array]:
-        """Pad + factor once per data array (id-keyed, strong-ref cache):
-        repeated warm-started ``fit``/``fit_path`` calls on the same data
-        skip the Gram + factorization entirely."""
-        N, M, nb = self._sizes(n)
-        n_pad = M * nb
-        if isinstance(A_global, jax.core.Tracer):
-            A_p = self._pad(A_global, n_pad)
-            return A_p, self._setup_factors(A_p, n)
-        key = (id(A_global), A_global.shape, str(A_global.dtype),
-               self._x_mode(nb))
-        hit = self._factor_cache.get(key)
-        if hit is not None:
-            return hit[1], hit[2]
-        A_p = self._pad(A_global, n_pad)
-        xfac = self._setup_factors(A_p, n)
-        if len(self._factor_cache) >= self._FACTOR_CACHE_MAX:
-            self._factor_cache.pop(next(iter(self._factor_cache)))
-        self._factor_cache[key] = (A_global, A_p, xfac)
-        return A_p, xfac
+    def _prepare(self, A: Array, b: Array) -> tuple[Array, Array, Array]:
+        """(A, b, x-update factors) as the programs take them, inside the
+        spans ``repro.layout`` and ``repro.setup``. A's layout and factors
+        are made once per design (``_factor_cache``): repeated fits on the
+        same A skip the relayout, the Gram and the factorization."""
+        n = A.shape[-1]
+        traced = isinstance(A, jax.core.Tracer)
+        key = (id(A), A.shape, str(A.dtype),
+               self._x_mode(self._sizes(n)[2]))
+        hit = None if traced else self._factor_cache.get(key)
+        with runtime.span("repro.layout"):
+            A_p = self._layout(A) if hit is None else hit[1]
+            b = self.cfg.precision.cast_data(b).reshape(
+                A_p.shape[:-1] + b.shape[A.ndim - 1:])
+        with runtime.span("repro.setup"):
+            xfac = self._setup_factors(A_p, n) if hit is None else hit[2]
+        if hit is None and not traced:
+            self._factor_cache = {key: (A, A_p, xfac)}
+        return A_p, b, xfac
 
     # ---- resumable state -----------------------------------------------------
     def init_state(self, n: int, n_samples: int,
@@ -549,7 +586,7 @@ class ShardedBiCADMM:
                 return (x_new, nu_new, om_new), None
             (x, nu, om), _ = jax.lax.scan(it, (x0, nu0, om0), None,
                                           length=cfg.inner_iters)
-            return x, nu, om
+            return x, nu, om, jnp.zeros((), jnp.int32)
 
         if x_mode == "cg":
             # Distributed matrix-free x-update: exact squared-loss node prox
@@ -586,12 +623,12 @@ class ShardedBiCADMM:
             inv = 1.0 / (xfac + c)                         # Jacobi precond
 
             def x_update(x0, nu0, om0, q):
-                xf, _ = prox.pcg(
+                xf, steps = prox.pcg(
                     lambda p: cg_adj(psum_f(cg_fwd(p))) + c * p,
                     Atb + cfg.rho_c * q[:, 0], x0[:, 0],
                     lambda r: inv * r, cfg.cg_iters, cfg.cg_tol,
                     dot_fn=cg_dot)
-                return xf[:, None], nu0, om0
+                return xf[:, None], nu0, om0, steps.astype(jnp.int32)
         else:
             x_update = inner_admm
 
@@ -645,7 +682,7 @@ class ShardedBiCADMM:
             full-vector projections as repro.core.bicadmm, replicated on
             every device. O(n) on the wire; opt-in (projection="exact")."""
             q = st.z - st.u
-            x_new, nu, om = x_update(st.x, st.nu, st.omega, q)
+            x_new, nu, om, steps = x_update(st.x, st.nu, st.omega, q)
             if cfg.over_relax != 1.0:
                 x_eff = cfg.over_relax * x_new + (1.0 - cfg.over_relax) * st.z
             else:
@@ -670,7 +707,8 @@ class ShardedBiCADMM:
                 jnp.linalg.norm(zg - zg_old)
             b_r = jnp.abs(gval)
             return ShardedState(x_new, u_new, z_new, t_new, s_new, v_new,
-                                nu, om, st.k + 1, p_r, d_r, b_r)
+                                nu, om, st.k + 1, p_r, d_r, b_r,
+                                st.cg + steps)
 
         def outer_step_ladder(st: ShardedState, kappa) -> ShardedState:
             """Default outer iteration: the exact sort-free projection
@@ -679,7 +717,7 @@ class ShardedBiCADMM:
             here with psum-wrapped reductions, so the only wire traffic of
             the (z,t,s,v) block is O(B)-sized ladder/polish statistics."""
             q = st.z - st.u
-            x_new, nu, om = x_update(st.x, st.nu, st.omega, q)
+            x_new, nu, om, steps = x_update(st.x, st.nu, st.omega, q)
             if cfg.over_relax != 1.0:
                 x_eff = cfg.over_relax * x_new + (1.0 - cfg.over_relax) * st.z
             else:
@@ -701,11 +739,12 @@ class ShardedBiCADMM:
                 jnp.sqrt(psum_f(jnp.sum((z_new - st.z) ** 2)))
             b_r = jnp.abs(gval)
             return ShardedState(x_new, u_new, z_new, t_new, s_new, v_new,
-                                nu, om, st.k + 1, p_r, d_r, b_r)
+                                nu, om, st.k + 1, p_r, d_r, b_r,
+                                st.cg + steps)
 
         def outer_step_sharded(st: ShardedState, kappa) -> ShardedState:
             q = st.z - st.u
-            x_new, nu, om = x_update(st.x, st.nu, st.omega, q)
+            x_new, nu, om, steps = x_update(st.x, st.nu, st.omega, q)
             if cfg.over_relax != 1.0:
                 x_eff = cfg.over_relax * x_new + (1.0 - cfg.over_relax) * st.z
             else:
@@ -734,7 +773,8 @@ class ShardedBiCADMM:
                 jnp.sqrt(psum_f(jnp.sum((z_new - st.z) ** 2)))
             b_r = jnp.abs(gval)
             return ShardedState(x_new, u_new, z_new, t_new, s_new, v_new,
-                                nu, om, st.k + 1, p_r, d_r, b_r)
+                                nu, om, st.k + 1, p_r, d_r, b_r,
+                                st.cg + steps)
 
         if mode == "exact":
             outer_step = outer_step_exact
@@ -746,7 +786,8 @@ class ShardedBiCADMM:
         big = jnp.asarray(jnp.inf, cfg.precision.state_dtype(A_blk.dtype))
 
         def reset(st: ShardedState) -> ShardedState:
-            return st._replace(k=jnp.asarray(0), p_r=big, d_r=big, b_r=big)
+            return st._replace(k=jnp.asarray(0), p_r=big, d_r=big, b_r=big,
+                               cg=jnp.zeros((), jnp.int32))
 
         return outer_step, reset
 
@@ -756,7 +797,8 @@ class ShardedBiCADMM:
         return ShardedState(
             x=gs.x[0], u=gs.u[0], z=gs.z, t=gs.t, s=gs.s, v=gs.v,
             nu=gs.nu, omega=gs.omega,
-            k=jnp.asarray(0), p_r=big, d_r=big, b_r=big)
+            k=jnp.asarray(0), p_r=big, d_r=big, b_r=big,
+            cg=jnp.zeros((), jnp.int32))
 
     @staticmethod
     def _pack_state(st: ShardedState) -> ShardedGlobalState:
@@ -768,6 +810,106 @@ class ShardedBiCADMM:
         K = self.loss.n_classes
         return z[:n].reshape(-1) if K > 1 else z.reshape(-1)[: n * K]
 
+    # ---- polish ----------------------------------------------------------------
+    def _polished(self, A_p: Array, b_p: Array, xs: Array, supports: Array,
+                  kappa_max) -> tuple[Array, Array]:
+        """The (P, n*K) thresholded iterates ``xs`` debiased on their
+        ``supports`` where ``cfg.polish`` is set and the loss is covered
+        (squared, logistic), with the polish's Newton steps (() int32, 0
+        for the squared loss's direct solve); else ``xs`` as they are.
+        ``kappa_max`` bounds the supports' size."""
+        cfg, loss = self.cfg, self.loss
+        if not cfg.polish or loss.name not in ("squared", "logistic"):
+            return xs, jnp.zeros((), jnp.int32)
+        n = xs.shape[1]
+        k_cap = max(1, min(n, math.ceil(float(kappa_max))))
+        key = ("polish", n, k_cap, A_p.ndim, b_p.ndim)
+        if key not in self._jit_cache:
+            self._jit_cache[key] = jax.jit(jax.shard_map(
+                self._polish_program(n, k_cap), mesh=self.mesh,
+                in_specs=(self._a_spec(A_p), self._b_spec(b_p), P(), P()),
+                out_specs=P(), check_vma=False))
+        with runtime.span("repro.polish"):
+            coef = self._jit_cache[key](A_p, b_p, xs, supports)
+        steps = cfg.newton_iters if loss.name == "logistic" else 0
+        return coef, jnp.asarray(steps, jnp.int32)
+
+    def _polish_program(self, n: int, k_cap: int):
+        """The shard-local body of ``repro_polish``: for each of P points,
+        the restricted optimum of the full problem on the support S,
+
+            min_{x_S}  sum_i l(A_i,S x_S, b_i) + 1/(2 gamma) ||x_S||^2,
+            x = 0 off S,
+
+        the objective of the single-process engine's ``_polish``. Each
+        device takes its rows' columns A_i[:, S] (``k_cap`` of them, the
+        unused ones zero) by a one-hot product, exact at HIGHEST; the
+        (k_cap, k_cap) Hessian and the k_cap-vector are psummed over
+        `nodes` and every device solves by Cholesky: for the squared loss
+        once and one refinement step, ``cfg.newton_iters`` Newton steps
+        from the thresholded iterate for the logistic loss."""
+        cfg, loss = self.cfg, self.loss
+        N, M, nb = self._sizes(n)
+        sigma = 1.0 / cfg.gamma          # the full problem's l2 weight
+        psum_n = _psum(self.nodes_axis)
+        feat = self.feat_axis
+
+        def repro_polish(A_loc, b_loc, xs, supports):
+            A_blk, b_blk = self._local_rows(A_loc, b_loc)
+            acc = cfg.precision.accum_dtype(A_blk.dtype)
+            b_blk = b_blk.astype(acc)
+            col0 = jax.lax.axis_index(feat) * nb if M > 1 else 0
+
+            def dot(u, w):
+                return jnp.dot(u, w, precision=F32_DOT,
+                               preferred_element_type=acc)
+
+            def one(x0, support):
+                (idx,) = jnp.nonzero(support, size=k_cap, fill_value=0)
+                on = jnp.arange(k_cap) < jnp.sum(support)
+                pick = ((idx - col0)[None, :] == jnp.arange(nb)[:, None]) \
+                    & on[None, :]
+                A_S = dot(A_blk, pick.astype(A_blk.dtype))     # (m_loc, k)
+                if M > 1:
+                    A_S = _psum(feat)(A_S)
+                diag = jnp.diag(jnp.where(on, sigma, 1.0).astype(acc))
+
+                def factor(H):
+                    # ridge on the support, identity rows for unused slots
+                    H = jnp.where(on[:, None] & on[None, :], H, 0.0) + diag
+                    return jax.scipy.linalg.cho_factor(H, lower=True)
+
+                def solve(cf, r):
+                    return jax.scipy.linalg.cho_solve(cf, jnp.where(on, r, 0.0))
+
+                if loss.name == "squared":
+                    cf = factor(psum_n(dot(A_S.T, A_S)))
+                    x = solve(cf, psum_n(dot(A_S.T, b_blk)))
+                    # one step of iterative refinement: the rounding of the
+                    # f32 Gram, summed over m_i rows, would stay in x; the
+                    # gradient taken from A_S rounds in proportion to the
+                    # residual instead
+                    g = psum_n(dot(A_S.T, dot(A_S, x) - b_blk))
+                    x = x - solve(cf, g + sigma * x)
+                else:
+                    def newton(_, x):
+                        pred = dot(A_S, x)
+                        g, d = jax.jvp(lambda p: loss.grad(p, b_blk),
+                                       (pred,), (jnp.ones_like(pred),))
+                        H, g = psum_n((dot(A_S.T, d[:, None] * A_S),
+                                       dot(A_S.T, g)))
+                        return x - solve(factor(H), g + sigma * x)
+                    x = jax.lax.fori_loop(
+                        0, cfg.newton_iters, newton,
+                        jnp.where(on, x0[idx], 0.0).astype(acc))
+                at = jnp.where(on, idx, n)              # unused slots drop
+                return jnp.zeros((n,), x0.dtype).at[at].set(
+                    x.astype(x0.dtype), mode="drop")
+
+            return jax.lax.map(lambda a: one(*a), (xs, supports))
+
+        return repro_polish
+
     # ---- public API ----------------------------------------------------------
     def fit(self, A_global: Array, b_global: Array, *,
             state: ShardedGlobalState | None = None,
@@ -775,28 +917,27 @@ class ShardedBiCADMM:
             ) -> ShardedResult:
         cfg = self.cfg
         K = self.loss.n_classes
-        A_global, b_global = self._cast(A_global, b_global)
-        n = A_global.shape[1]
+        n = A_global.shape[-1]
         N, M, nb = self._sizes(n)
         n_pad = M * nb
-        A_p, xfac = self._prepare(A_global, n)
+        A_p, b_p, xfac = self._prepare(A_global, b_global)
         sdt = cfg.precision.state_dtype(A_p.dtype)
         iters = iters if iters is not None else cfg.max_iter
         if state is None:
-            state = self.init_state(n, A_global.shape[0], sdt)
+            state = self.init_state(n, math.prod(A_p.shape[:-1]), sdt)
 
         nodes = self.nodes_axis
         st_specs = self._state_specs()
         fac_spec = P(nodes, self.feat_axis, *([None] * (xfac.ndim - 2)))
-        in_specs = (P(nodes, self.feat_axis),
-                    P(nodes) if b_global.ndim == 1 else P(nodes, None),
-                    fac_spec, st_specs)
+        in_specs = (self._a_spec(A_p), self._b_spec(b_p), fac_spec,
+                    st_specs)
         # z / history / scalars are replicated over `nodes`; z is
         # feat-sharded on its leading dim.
         out_specs = ((P(self.feat_axis, None), P(), P(), P(), P(), P()),
                      P(None, None), st_specs)
 
-        def run(A_blk, b_blk, xf, gs):
+        def repro_loop(A_loc, b_loc, xf, gs):
+            A_blk, b_blk = self._local_rows(A_loc, b_loc)
             outer_step, _ = self._local_funcs(N, M, A_blk, b_blk, xf[0, 0])
             st0 = self._unpack_state(gs, sdt)
             kappa = jnp.asarray(float(cfg.kappa), sdt)
@@ -819,28 +960,36 @@ class ShardedBiCADMM:
                     return (~done) & (~diverged) & (st.k < iters)
                 st = jax.lax.while_loop(cond, step, st0)
                 hist = jnp.zeros((iters, 3), sdt)
-            return ((st.z, st.k, st.p_r, st.d_r, st.b_r, st.t), hist,
+            # the slowest node's CG steps: the one the consensus psum awaits
+            cg = _pmax(nodes)(st.cg)
+            return ((st.z, st.k, st.p_r, st.d_r, st.b_r, cg), hist,
                     self._pack_state(st))
 
-        key = ("fit", n, b_global.ndim, record_history, iters)
+        key = ("fit", n, A_p.ndim, b_p.ndim, record_history, iters)
         if key not in self._jit_cache:
             # the state pytree is donated: its iterate buffers are reused
             # in place by the while-loop (fit consumes a passed-in state —
             # keep using the returned result.state)
             self._jit_cache[key] = jax.jit(jax.shard_map(
-                run, mesh=self.mesh, in_specs=in_specs,
+                repro_loop, mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False), donate_argnums=(3,))
-        (z, k, p_r, d_r, b_r, t), hist, gs = \
-            self._jit_cache[key](A_p, b_global, xfac, state)
+        with runtime.span("repro.loop"):
+            (z, k, p_r, d_r, b_r, cg), hist, gs = \
+                self._jit_cache[key](A_p, b_p, xfac, state)
 
-        zf = self._unpad_flat(z, n, n_pad)
-        z_sparse = bilinear.hard_threshold(zf, cfg.kappa)
-        support = jnp.abs(z_sparse) > 0
-        status = classify_status(k, p_r, d_r, b_r, tol=cfg.tol,
-                                 divergence_tol=cfg.divergence_tol)
-        return FitResult(z_sparse.reshape(n, K), zf, support, k, p_r, d_r,
+        with runtime.span("repro.finalize"):
+            zf = self._unpad_flat(z, n, n_pad)
+            z_sparse = bilinear.hard_threshold(zf, cfg.kappa)
+            support = jnp.abs(z_sparse) > 0
+            coef, polish_steps = self._polished(
+                A_p, b_p, z_sparse[None], support[None], cfg.kappa)
+            status = classify_status(k, p_r, d_r, b_r, tol=cfg.tol,
+                                     divergence_tol=cfg.divergence_tol)
+        return FitResult(coef.reshape(n, K), zf, support, k, p_r, d_r,
                          b_r, hist if record_history else None, gs,
-                         status=status)
+                         status=status,
+                         counters={"xupdate_cg_steps": cg,
+                                   "polish_cg_steps": polish_steps})
 
     def fit_path(self, A_global: Array, b_global: Array, kappas, *,
                  state: ShardedGlobalState | None = None,
@@ -848,31 +997,31 @@ class ShardedBiCADMM:
         """Fit the whole kappa-path in one shard_map'd ``lax.scan``: each
         budget's while-loop warm-starts from the previous budget's ADMM
         state (``warm_start=False`` re-initializes per point — the cold
-        baseline with identical numerics and collectives)."""
+        baseline with identical numerics and collectives). Each point is
+        polished as ``fit`` polishes."""
         cfg = self.cfg
         K = self.loss.n_classes
-        A_global, b_global = self._cast(A_global, b_global)
-        n = A_global.shape[1]
+        n = A_global.shape[-1]
         N, M, nb = self._sizes(n)
         n_pad = M * nb
-        A_p, xfac = self._prepare(A_global, n)
+        A_p, b_p, xfac = self._prepare(A_global, b_global)
         sdt = cfg.precision.state_dtype(A_p.dtype)
         kaps = jnp.asarray(kappas, sdt)
         if kaps.ndim != 1 or kaps.shape[0] == 0:
             raise ValueError("kappas must be a non-empty 1-D grid")
         if state is None:
-            state = self.init_state(n, A_global.shape[0], sdt)
+            state = self.init_state(n, math.prod(A_p.shape[:-1]), sdt)
 
         nodes = self.nodes_axis
         st_specs = self._state_specs()
         fac_spec = P(nodes, self.feat_axis, *([None] * (xfac.ndim - 2)))
-        in_specs = (P(nodes, self.feat_axis),
-                    P(nodes) if b_global.ndim == 1 else P(nodes, None),
-                    fac_spec, P(), st_specs)
+        in_specs = (self._a_spec(A_p), self._b_spec(b_p), fac_spec, P(),
+                    st_specs)
         out_specs = ((P(None, self.feat_axis, None), P(None), P(None),
                       P(None), P(None)), st_specs)
 
-        def run(A_blk, b_blk, xf, ks, gs):
+        def run(A_loc, b_loc, xf, ks, gs):
+            A_blk, b_blk = self._local_rows(A_loc, b_loc)
             outer_step, reset = self._local_funcs(N, M, A_blk, b_blk,
                                                   xf[0, 0])
             st_init = self._unpack_state(gs, sdt)
@@ -897,23 +1046,24 @@ class ShardedBiCADMM:
             last, outs = jax.lax.scan(solve_one, st_init, ks)
             return outs, self._pack_state(last)
 
-        key = ("path", n, b_global.ndim, warm_start)
+        key = ("path", n, A_p.ndim, b_p.ndim, warm_start)
         if key not in self._jit_cache:
             # state donated: path iterate buffers are reused in place
             self._jit_cache[key] = jax.jit(jax.shard_map(
                 run, mesh=self.mesh, in_specs=in_specs,
                 out_specs=out_specs, check_vma=False), donate_argnums=(4,))
         (z, k, p_r, d_r, b_r), gs = \
-            self._jit_cache[key](A_p, b_global, xfac, kaps, state)
+            self._jit_cache[key](A_p, b_p, xfac, kaps, state)
 
         zf = jax.vmap(lambda zz: self._unpad_flat(zz, n, n_pad))(z)
         x_sparse = jax.vmap(bilinear.hard_threshold)(zf, kaps)
         support = jnp.abs(x_sparse) > 0
+        coef, _ = self._polished(A_p, b_p, x_sparse, support, jnp.max(kaps))
         npts = kaps.shape[0]
         fill = lambda v: jnp.full((npts,), v, kaps.dtype)
         status = classify_status(k, p_r, d_r, b_r, tol=cfg.tol,
                                  divergence_tol=cfg.divergence_tol)
-        return SparsePath(x_sparse.reshape(npts, n, K), zf, support, k,
+        return SparsePath(coef.reshape(npts, n, K), zf, support, k,
                           p_r, d_r, b_r, jnp.sum(support, axis=1), kaps,
                           fill(cfg.gamma), fill(cfg.rho_c), state=gs,
                           strategy="warm-scan" if warm_start else "cold-scan",

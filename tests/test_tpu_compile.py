@@ -1,4 +1,5 @@
-"""Compile the main-path kernels and whole fits for one TPU v5e chip.
+"""Compile the main-path kernels and whole fits for one TPU v5e chip, and
+the sharded engine's polish for a four-chip v5e host.
 
 Nothing runs: the TPU compiler that ships with jax compiles for a chip
 that is described, not attached, so these tests catch what interpret mode
@@ -30,11 +31,10 @@ V5E_HBM = int(15.75 * 2**30)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
@@ -45,8 +45,14 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 def _sds(shape, dtype, sharding):
@@ -213,3 +219,33 @@ def test_table1_polish_compiles_within_hbm(one_chip):
     assert mem.temp_size_in_bytes < N * m * n * 4 // 4
     _assert_kernel_reads_a_in_place(c, N * m * n)
     assert not _lane_pads(c.as_text(), n)
+
+
+def test_sharded_polish_compiles_on_four_chips(v5e_2x2):
+    """The sharded engine's polish at the paper's own deployment, A (4,
+    300000, 4000) with a node's rows on each chip of a (nodes 4, feat 1)
+    mesh, fits each chip: its temp is the (m_i, kappa) support columns,
+    no copy of a chip's 4.8 GB block."""
+    import numpy as np
+    from jax.sharding import AxisType, Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    from repro.core.sharded import ShardedBiCADMM
+    N, m, n, kappa = 4, 300000, 4000, 400
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(4, 1), ("nodes", "feat"),
+                axis_types=(AxisType.Auto,) * 2)
+    solver = ShardedBiCADMM("squared", BiCADMMConfig(kappa=kappa,
+                                                     gamma=1000.0), mesh)
+    on = lambda spec: NamedSharding(mesh, spec)
+    A = _sds((N, m, n), jnp.float32, on(P("nodes", None, "feat")))
+    b = _sds((N, m), jnp.float32, on(P("nodes", None)))
+    c = jax.jit(jax.shard_map(
+        solver._polish_program(n, kappa), mesh=mesh,
+        in_specs=(solver._a_spec(A), solver._b_spec(b), P(), P()),
+        out_specs=P(), check_vma=False)).lower(
+        A, b, _sds((1, n), jnp.float32, on(P())),
+        _sds((1, n), jnp.bool_, on(P()))).compile()
+    mem = c.memory_analysis()
+    assert mem.temp_size_in_bytes < m * n * 4 // 4
+    hlo = c.as_text()
+    assert hlo.startswith("HloModule jit_repro_polish")
+    assert "all-reduce" in hlo                  # the psum over nodes

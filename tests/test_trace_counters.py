@@ -1,4 +1,4 @@
-"""The reference engine's spans, named device programs and inner-step
+"""Both engines' spans, named device programs and inner-step
 counters: ``FitResult.counters`` (x-update and polish CG steps), the
 ``repro.*`` host spans a fit opens (``repro.runtime.SPANS``), and the
 ``jit_repro_loop`` / ``jit_repro_polish`` program names."""
@@ -200,3 +200,78 @@ def test_fit_spans_in_order_and_nested(tmp_path):
     assert fin[1] <= pol[1] <= pol[2] <= fin[2]
     (loop,) = [e for e in spans if e[0] == "repro.loop"]
     assert loop[2] <= fin[1]
+
+
+# ------------------------------------------------------ the sharded engine --
+def _sharded(loss="squared", **opts):
+    mesh = jax.make_mesh((1, 1), ("nodes", "feat"))
+    cls = {"squared": api.SparseLinearRegression,
+           "logistic": api.SparseLogisticRegression}[loss]
+    return cls(KAPPA, gamma=100.0, options=api.SolverOptions(
+        engine="sharded", mesh=mesh, max_iter=40, **opts))
+
+
+@pytest.mark.parametrize("loss,x_update", [("squared", "cg"),
+                                           ("squared", "subsolver"),
+                                           ("logistic", "auto")])
+def test_sharded_fit_carries_counters(loss, x_update):
+    """The x-update's CG steps (the slowest node's; none outside the PCG
+    x-update) and the polish's Newton steps (none for the direct solve)."""
+    As, bs = _data(10, logistic=loss == "logistic")
+    if loss == "logistic":
+        bs = 2.0 * bs - 1.0
+    est = _sharded(loss, x_update=x_update)
+    est.fit(As, bs)
+    c = _counts(est.result_)
+    assert set(c) == {"xupdate_cg_steps", "polish_cg_steps"}
+    assert all(v.dtype == jnp.int32 for v in est.result_.counters.values())
+    iters = int(est.result_.iters)
+    if x_update == "cg":
+        assert iters <= c["xupdate_cg_steps"] <= iters * est.options.cg_iters
+    else:
+        assert c["xupdate_cg_steps"] == 0
+    want = est.options.newton_iters if loss == "logistic" else 0
+    assert c["polish_cg_steps"] == want
+
+
+def test_sharded_programs_are_named():
+    As, bs = _data(11)
+    est = _sharded(x_update="cg")
+    est.fit(As, bs)
+    solver = est._adapters["sharded"].solver
+    A_p, b_p, xfac = solver._prepare(As, bs)
+    progs = {k[0]: f for k, f in solver._jit_cache.items()}
+    state = solver.init_state(NF, N * M, jnp.float32)
+    loop = progs["fit"].lower(A_p, b_p, xfac, state)
+    assert "@jit_repro_loop" in loop.as_text().splitlines()[0]
+    res = est.result_
+    polish = progs["polish"].lower(A_p, b_p, res.coef.reshape(1, -1),
+                                   res.support[None])
+    assert "@jit_repro_polish" in polish.as_text().splitlines()[0]
+
+
+def test_sharded_fit_spans_in_order_and_nested(tmp_path):
+    """A traced sharded fit opens every span of ``runtime.SPANS``, in that
+    order, inside the caller's ``fit`` span, the polish inside the
+    finalize, after the loop."""
+    As, bs = _data(12)
+    est = _sharded(x_update="cg")
+    est.fit(As, bs)                                   # compile outside
+    jax.block_until_ready(est.result_.coef)
+    with jax.profiler.trace(str(tmp_path)):
+        with jax.profiler.TraceAnnotation("fit"):
+            est.fit(As, bs)
+            jax.block_until_ready(est.result_.coef)
+    events = _host_events(str(tmp_path))
+    (fit,) = [e for e in events if e[0] == "fit"]
+    spans = [e for e in events if e[0] != "fit"]
+    first = []
+    for name, *_ in spans:
+        if name not in first:
+            first.append(name)
+    assert first == list(runtime.SPANS)
+    assert all(fit[1] <= s <= e <= fit[2] for _, s, e in spans)
+    (fin,) = [e for e in spans if e[0] == "repro.finalize"]
+    (pol,) = [e for e in spans if e[0] == "repro.polish"]
+    (loop,) = [e for e in spans if e[0] == "repro.loop"]
+    assert loop[2] <= fin[1] <= pol[1] <= pol[2] <= fin[2]

@@ -195,7 +195,7 @@ def test_sharded_single_device_cg_matches_reference_pcg():
               cg_tol=1e-7)
     ref = BiCADMM("squared", BiCADMMConfig(**kw, polish=False)).fit(As, bs)
     mesh = jax.make_mesh((1, 1), ("nodes", "feat"))
-    res = ShardedBiCADMM("squared", BiCADMMConfig(**kw), mesh,
+    res = ShardedBiCADMM("squared", BiCADMMConfig(**kw, polish=False), mesh,
                          x_update="cg").fit(As.reshape(-1, 40),
                                             bs.reshape(-1))
     assert int(res.iters) == int(ref.iters)
